@@ -150,7 +150,8 @@ def run_pipeline_arm(pipeline: bool, preempt_every: int = 0, *,
     try:
         for r in shell.regions:  # bitstreams warm: measure dispatch, not
             shell.engine.prewarm("MedianBlur", bundle, r.geometry,  # compile
-                                 program=shell.prefetcher.program)
+                                 program=shell.prefetcher.program,
+                                 devices=r.devices)
         regions = shell.regions
         target = regions[0]
         target.enqueue_reconfig(task)

@@ -15,12 +15,9 @@ form of the paper's latency-hiding claim.
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
 import time
 
+import jax
 import numpy as np
 
 from repro.controller.kernels import get_kernel
@@ -95,27 +92,25 @@ def _prefetch_workload(prefetch: bool, *, slowdown_s: float,
 
 
 def _prefetch_arm(prefetch: bool, slowdown_s: float) -> dict:
-    """Run one arm in a fresh subprocess: XLA's in-process compilation cache
-    would otherwise warm the second arm (and anything `measure()` compiled
-    earlier), understating the cold-compile stalls being compared."""
-    code = (
-        "import json\n"
-        "from benchmarks.bench_reconfig import _prefetch_workload\n"
-        f"rep = _prefetch_workload({prefetch!r}, slowdown_s={slowdown_s!r})\n"
-        "keep = ('dispatch_stall_s', 'cold_compiles', 'prefetch_hit_rate',"
-        " 'wall_s', 'n_done')\n"
-        "print('ARM_JSON=' + json.dumps({k: rep[k] for k in keep}))\n")
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(root, "src"), root]
-        + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
-    out = subprocess.run([sys.executable, "-c", code], cwd=root, env=env,
-                         capture_output=True, text=True, timeout=600)
-    for line in out.stdout.splitlines():
-        if line.startswith("ARM_JSON="):
-            return json.loads(line[len("ARM_JSON="):])
-    raise RuntimeError(f"prefetch arm failed:\n{out.stderr[-2000:]}")
+    """Run one arm from cold, in this process (one process owns the chip):
+    XLA's in-process caches are cleared and JAX's persistent compilation
+    cache is off for the arm, so neither arm — nor what ``measure()``
+    compiled earlier — finds a warm bitstream and understates the
+    cold-compile stalls being compared."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.clear_caches()
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        rep = _prefetch_workload(prefetch, slowdown_s=slowdown_s)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was_on)
+        compilation_cache.reset_cache()
+    keep = ("dispatch_stall_s", "cold_compiles", "prefetch_hit_rate",
+            "wall_s", "n_done")
+    return {k: rep[k] for k in keep}
 
 
 def measure_prefetch(printer=print, slowdown_s: float = 0.15) -> dict:

@@ -256,8 +256,9 @@ def run_cluster_cell(arm: str, *, n_bursts: int = 3, burst: int = 8,
             r.slowdown_s = slowdown
         for kname in kernels:
             ex = next(t for t in tasks if t.kernel == kname)
-            for geom in node.shell.geometries():
-                node.shell.engine.prewarm(kname, ex.args, geom)
+            for geom, devs in node.shell.placements():
+                node.shell.engine.prewarm(kname, ex.args, geom,
+                                          devices=devs)
 
     handles = []
     forced = 0

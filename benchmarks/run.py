@@ -104,6 +104,9 @@ def main() -> None:
     ap.add_argument("--no-cache", action="store_true")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     print("name,us_per_call,derived")
 
     # kernel microbenches first (cheap)

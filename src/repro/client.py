@@ -121,10 +121,14 @@ class Client:
         """Convenience: build the ``Task`` from a registered kernel's
         declared argument names (the old ``Controller.launch``) and
         submit it immediately."""
+        from repro.controller.hittile import HitTile
         from repro.controller.kernels import get_kernel
 
         kd = get_kernel(kernel)
-        bufs = tuple(h.data if hasattr(h, "data") else h for h in hittiles)
+        # a HitTile carries its array in .data; a bare array's own .data
+        # is its raw memoryview, which must not stand in for it
+        bufs = tuple(h.data if isinstance(h, HitTile) else h
+                     for h in hittiles)
         task = Task(kernel=kernel, args=kd.bundle(*bufs, **scalars),
                     priority=priority, tenant=tenant)
         return self.submit(task)

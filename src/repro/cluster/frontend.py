@@ -44,6 +44,8 @@ import time
 from dataclasses import dataclass, field, replace
 from typing import Dict, List, Optional, Sequence, Union
 
+import jax
+
 from repro.ckpt.store import (CheckpointCorruptError, load_pytree,
                               save_pytree)
 from repro.cluster.node import ClusterNode, NodePowerModel
@@ -249,6 +251,11 @@ class ClusterFrontend:
         else:
             if n_shells < 1:
                 raise ValueError(f"n_shells must be >= 1, got {n_shells}")
+            # shell i owns device i when the host has one per shell (each
+            # replica on its own chip); otherwise every shell time-shares
+            # the whole grid, as on a one-device CPU host
+            devs = jax.devices()
+            own = "devices" not in shell_kwargs and len(devs) >= n_shells
             self.nodes = [
                 ClusterNode(
                     i, n_regions=regions_per_shell,
@@ -256,6 +263,7 @@ class ClusterFrontend:
                     power=(power_models[i] if power_models else None),
                     tracer=tracer,
                     metrics=metrics,
+                    **({"devices": [devs[i]]} if own else {}),
                     **shell_kwargs)
                 for i in range(n_shells)]
         self.router: RouterPolicy = (
@@ -439,6 +447,20 @@ class ClusterFrontend:
         ``"any"`` prefers queued — the cheap move — then running.
         Gracefully returns False when the task finishes first, the source
         is already drained, or no target shell qualifies."""
+        return self._migrate(tid, source, target, prefer, timeout,
+                             self._take_task)
+
+    def _migrate_at_boundary(self, tid: int, at_boundary: int,
+                             timeout: Optional[float] = None) -> bool:
+        """Test hook: a deterministic checkpoint migration of task ``tid``.
+        It is not cancelled while queued: its next launch stops at chunk
+        boundary ``at_boundary`` (``Task.preempt_at_boundary``) and it
+        moves, checkpoint and all, to the shell the router picks."""
+        def take(rec, src, timeout):
+            return self._take_at_boundary(rec, src, timeout, at_boundary)
+        return self._migrate(tid, None, None, "any", timeout, take)
+
+    def _migrate(self, tid, source, target, prefer, timeout, take) -> bool:
         with self._lock:
             rec, src = self._pick_migration(tid, source, prefer)
             if rec is None:
@@ -460,7 +482,7 @@ class ClusterFrontend:
                 rec, src,
                 self.nodes[target] if target is not None else None,
                 timeout=self.migrate_timeout_s if timeout is None
-                else timeout)
+                else timeout, take=take)
         finally:
             with self._lock:
                 rec.migrating = False
@@ -514,10 +536,11 @@ class ClusterFrontend:
         return max(cands, key=lambda r: r.t_submit), src
 
     def _do_migrate(self, rec: _Record, src: ClusterNode,
-                    target: Optional[ClusterNode], timeout: float) -> bool:
+                    target: Optional[ClusterNode], timeout: float,
+                    take) -> bool:
         task = rec.task
         t_mig0 = time.perf_counter()
-        if not self._take_task(rec, src, timeout):
+        if not take(rec, src, timeout):
             return False
         # we own the task: its source handle is settled, its context (if
         # it ever ran) is committed in task.saved_context
@@ -572,6 +595,31 @@ class ClusterFrontend:
                         if r.current_task is task:
                             r.request_preempt()
                             break
+        finally:
+            sched.cancel_handoff(task.tid)
+        return handed.is_set()
+
+    def _take_at_boundary(self, rec: _Record, src: ClusterNode,
+                          timeout: float, at_boundary: int) -> bool:
+        """Detach ``rec.task`` at chunk boundary ``at_boundary`` of its
+        next launch (never by cancelling it).  The handoff is requested
+        before the stop is armed, so the stop hands the checkpoint over.
+        False when the task completed first or the node died."""
+        task, inner = rec.task, rec.inner
+        handed = threading.Event()
+        sched = src.scheduler
+        sched.request_handoff(task.tid, lambda t: handed.set())
+        task.preempt_at_boundary = at_boundary
+        deadline = time.perf_counter() + timeout
+        try:
+            while not handed.wait(0.004):
+                if (inner.done() and not inner.migrated()) or not src.healthy:
+                    return False
+                if time.perf_counter() > deadline:
+                    if sched.cancel_handoff(task.tid):
+                        return False
+                    handed.wait(1.0)            # fired concurrently
+                    break
         finally:
             sched.cancel_handoff(task.tid)
         return handed.is_set()

@@ -164,13 +164,13 @@ class ClusterNode:
 
     def has_bitstream(self, task: Task) -> bool:
         """True when this shell's reconfig cache already holds the task's
-        executable for any current region geometry — routing here saves
+        executable for any current region placement — routing here saves
         the bitstream generation entirely (the affinity router's signal)."""
         engine = self.shell.engine
         sig = task.args.signature()
         program = self.shell.prefetcher.program  # this shell's program kind
-        return any(engine.cache_key(task.kernel, sig, g, program)
-                   in engine.cache for g in self.shell.geometries())
+        return any(engine.cache_key(task.kernel, sig, g, program, devs)
+                   in engine.cache for g, devs in self.shell.placements())
 
     def submit(self, task: Task) -> TaskHandle:
         return self.scheduler.submit(task)

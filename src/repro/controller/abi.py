@@ -14,7 +14,6 @@ from dataclasses import dataclass, field
 from typing import Any, Optional, Sequence, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 N_BUF_SLOTS = 6    # pointer args (HitTiles); unused slots hold (1,1) dummies
@@ -35,13 +34,16 @@ class ArgBundle:
     # memoized padded() result: a preempted/migrated task is re-dispatched
     # many times, and re-padding + re-uploading the scalar vectors on every
     # launch is pure overhead — the bundle is immutable after creation.
-    # The int/float vectors are device arrays reused across dispatches
-    # (they are never donated); the buffer slots stay host numpy — the
-    # launch path uploads them once and thereafter the payload lives
-    # device-resident in the chunk pipeline.
+    # The buffer slots stay host numpy (the launch path uploads them once
+    # and thereafter the payload lives device-resident in the chunk
+    # pipeline); the int/float vectors are device arrays reused across
+    # dispatches (never donated), memoized per target device.
     _padded: Optional[tuple] = field(default=None, repr=False, compare=False)
+    _scalars: dict = field(default_factory=dict, repr=False, compare=False)
 
-    def padded(self):
+    def padded(self, device=None):
+        """``(bufs, ints, floats)`` at the uniform ABI width, with the
+        scalar vectors on ``device`` (``None``: JAX's default device)."""
         if self._padded is None:
             bufs = list(self.bufs)[:N_BUF_SLOTS]
             while len(bufs) < N_BUF_SLOTS:
@@ -50,18 +52,30 @@ class ArgBundle:
             ints += [0] * (N_INT_ARGS - len(ints))
             floats = list(self.floats)[:N_FLOAT_ARGS]
             floats += [0.0] * (N_FLOAT_ARGS - len(floats))
-            self._padded = (tuple(bufs), jnp.asarray(ints, jnp.int32),
-                            jnp.asarray(floats, jnp.float32))
-        return self._padded
+            self._padded = (tuple(bufs), np.asarray(ints, np.int32),
+                            np.asarray(floats, np.float32))
+        bufs, ints, floats = self._padded
+        scalars = self._scalars.get(device)
+        if scalars is None:
+            scalars = self._scalars[device] = (
+                jax.device_put(ints, device), jax.device_put(floats, device))
+        return (bufs,) + scalars
 
     def signature(self) -> tuple:
         """Shape/dtype signature — the 'interface' a region must be
         configured for (kernel + signature = one executable)."""
         if self._sig is None:
             bufs, _, _ = self.padded()
-            self._sig = tuple((tuple(b.shape), jnp.asarray(b).dtype.name)
+            self._sig = tuple((tuple(b.shape), buffer_dtype(b).name)
                               for b in bufs)
         return self._sig
+
+
+def buffer_dtype(b) -> np.dtype:
+    """The dtype a buffer has on the device (64-bit host types narrow
+    unless x64 is on), read without uploading the buffer."""
+    dtype = b.dtype if hasattr(b, "dtype") else np.asarray(b).dtype
+    return jax.dtypes.canonicalize_dtype(dtype)
 
 
 def abi_signature(bundle: ArgBundle) -> tuple:

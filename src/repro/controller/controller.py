@@ -22,6 +22,7 @@ import time
 import warnings
 from typing import Dict, List
 
+from repro.controller.hittile import HitTile
 from repro.controller.kernels import get_kernel
 from repro.core.scheduler import Scheduler, SchedulerConfig
 from repro.core.shell import Shell
@@ -63,7 +64,8 @@ class Controller:
         """Enqueue a kernel-execution task (Controller model: tasks are
         queued, the runtime resolves placement/transfers)."""
         kd = get_kernel(kernel)
-        bufs = tuple(h.data if hasattr(h, "data") else h for h in hittiles)
+        bufs = tuple(h.data if isinstance(h, HitTile) else h
+                     for h in hittiles)
         bundle = kd.bundle(*bufs, **scalars)
         task = Task(kernel=kernel, args=bundle, priority=priority,
                     arrival_time=arrival_time)

@@ -132,25 +132,28 @@ class PreemptFlag:
     deterministic boundary placement.
 
     The flag lives in a one-element ``int32`` device buffer that is passed
-    to the compiled megakernel as a *non-donated* argument.  On this CPU
+    to the compiled megakernel as a *non-donated* argument.  On the CPU
     backend the buffer is host memory, so a host store is visible to the
     running ``while_loop`` within one iteration — the zero-copy "device
     put" the FPGA's AXI preempt line maps to.  ``np.asarray`` of a jax
     array is zero-copy but read-only; the writable view is built over the
     same bytes via ``unsafe_buffer_pointer`` (an aligned ``int32`` store
     is atomic on every ISA the CPU backend targets, so the device-side
-    reader can never observe a torn value).
+    reader can never observe a torn value).  Any other platform's buffer
+    is device memory that a host store must never write through, so the
+    platform is checked before the pointer is ever read.
     """
 
-    def __init__(self):
-        self._dev = jnp.zeros((1,), jnp.int32)
-        jax.block_until_ready(self._dev)
-        try:
-            ptr = self._dev.unsafe_buffer_pointer()
-        except Exception as e:  # pragma: no cover - non-CPU backends
+    def __init__(self, device=None):
+        device = device if device is not None else jax.devices()[0]
+        if device.platform != "cpu":
             raise RuntimeError(
-                "engine='megakernel' needs a host-mappable flag buffer "
-                "(jax CPU backend); use the pipelined engine here") from e
+                f"engine='megakernel' needs a host-mappable preempt flag, "
+                f"which only the CPU backend has; {device} is a "
+                f"{device.platform} device — use engine='pipelined'")
+        self._dev = jnp.zeros((1,), jnp.int32, device=device)
+        jax.block_until_ready(self._dev)
+        ptr = self._dev.unsafe_buffer_pointer()
         self._view = np.ctypeslib.as_array(
             ctypes.cast(ptr, ctypes.POINTER(ctypes.c_int32)), shape=(1,))
         self._view[0] = 0

@@ -33,6 +33,7 @@ class PrefetchRequest:
     bundle: object           # ArgBundle
     geometry: tuple
     task: Optional[Task] = None
+    devices: Optional[list] = None   # the region's devices (compile target)
 
 
 @dataclass
@@ -89,11 +90,18 @@ class BitstreamPrefetcher:
         return self._thread is not None and self._thread.is_alive()
 
     # ------------------------------------------------------------------
-    def submit(self, task: Task, geometries: Iterable[tuple]):
+    def submit(self, task: Task, placements: Iterable[tuple]):
         """Hint: ``task`` just entered a priority queue; warm its bitstream
-        for every distinct region geometry it could land on."""
-        for geom in dict.fromkeys(tuple(g) for g in geometries):
-            req = PrefetchRequest(task.kernel, task.args, geom, task)
+        for every distinct region placement (``Shell.placements()``:
+        ``(geometry, devices)`` pairs) it could land on."""
+        seen = set()
+        for geom, devices in placements:
+            key = (tuple(geom), self.engine.target(devices).id)
+            if key in seen:
+                continue
+            seen.add(key)
+            req = PrefetchRequest(task.kernel, task.args, tuple(geom), task,
+                                  devices)
             with self._cv:
                 try:
                     self._q.put_nowait(req)
@@ -124,7 +132,7 @@ class BitstreamPrefetcher:
         try:
             self.engine.prefetch(req.kernel, req.bundle, req.geometry,
                                  still_wanted=still_wanted,
-                                 program=self.program)
+                                 program=self.program, devices=req.devices)
         except Exception:  # pragma: no cover - a broken hint must not
             import traceback  # kill the prefetcher; the demand path will
 

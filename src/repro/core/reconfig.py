@@ -1,7 +1,8 @@
 """Reconfiguration engine (paper §4.1/4.2).
 
 "Bitstreams" are compiled XLA executables keyed by (kernel, ABI signature,
-region geometry).  Partial reconfiguration = swapping one region's loaded
+region geometry, program, device): each is compiled for the one device its
+region runs on.  Partial reconfiguration = swapping one region's loaded
 executable (cache hit: fast; cold compile: the bitstream-generation cost).
 Full reconfiguration = tearing down every region and reloading (the paper's
 baseline, §6.3 red lines).  The single ICAP port becomes a global lock: at
@@ -32,8 +33,10 @@ from dataclasses import dataclass, field
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
+import numpy as np
+from jax.sharding import SingleDeviceSharding
 
-from repro.controller.abi import ArgBundle
+from repro.controller.abi import ArgBundle, buffer_dtype
 from repro.controller.kernels import KernelDef, get_kernel
 from repro.core.context import ContextRecord
 
@@ -155,11 +158,24 @@ class _Inflight:
         self.error: Optional[BaseException] = None
 
 
+def placement_device(devices):
+    """The JAX device a region over ``devices`` runs on: the first one, or
+    None (JAX's default device) when there is none — floorplanning tests
+    plan over stand-in objects that no program can be placed on."""
+    first = devices[0] if devices else None
+    return first if isinstance(first, jax.Device) else None
+
+
 class ReconfigEngine:
     def __init__(self, simulate_partial_s: float = 0.0,
                  simulate_full_s: float = 0.0,
-                 cache_capacity: Optional[int] = None):
+                 cache_capacity: Optional[int] = None,
+                 device=None):
         self.cache = LRUBitstreamCache(cache_capacity)
+        # home device: where a bitstream is compiled for when the caller
+        # names no devices (the owning Shell passes its first device;
+        # None = JAX's default device)
+        self.device = placement_device([device])
         self._icap = threading.Lock()  # single ICAP port (the load itself)
         # flight recorder handle (obs/, DESIGN.md §11); the owning Shell
         # threads it in.  Emits ICAP hold/wait and compile spans.
@@ -174,14 +190,21 @@ class ReconfigEngine:
         self._lock = threading.Lock()  # stats + inflight table
         self._inflight: Dict[tuple, _Inflight] = {}
 
+    def target(self, devices=None):
+        """The device a bitstream for ``devices`` is compiled for: the
+        first of them, else the home device."""
+        return (placement_device(devices) or self.device
+                or jax.devices()[0])
+
     def cache_key(self, kernel: str, sig: tuple, geometry: tuple,
-                  program: str = "chunk") -> tuple:
+                  program: str = "chunk", devices=None) -> tuple:
         """``program`` selects the compiled entry point: ``"chunk"`` (one
         budget-bounded chunk per dispatch — the sync/pipelined engines) or
         ``"mega"`` (the on-device while-loop over the same body — the
         megakernel engine).  Same kernel + signature + geometry, distinct
-        bitstreams."""
-        return (kernel, sig, geometry, program)
+        bitstreams; and one per target device (``devices``), since an
+        executable runs only on the device it was compiled for."""
+        return (kernel, sig, geometry, program, self.target(devices).id)
 
     def _key_stats(self, key: tuple) -> KeyStats:
         # caller holds self._lock
@@ -199,7 +222,7 @@ class ReconfigEngine:
         reconfigurations proceed meanwhile."""
         kd = get_kernel(kernel_name)
         key = self.cache_key(kernel_name, bundle.signature(), geometry,
-                             program)
+                             program, devices)
         t0 = time.perf_counter()
 
         entry = self.cache.get(key)
@@ -213,7 +236,8 @@ class ReconfigEngine:
                     self.stats.prefetch_hits += 1
         else:
             t_stall0 = time.perf_counter()
-            entry = self._get_or_compile(key, kd, bundle, devices,
+            entry = self._get_or_compile(key, kd, bundle,
+                                         self.target(devices),
                                          origin=ORIGIN_DEMAND,
                                          program=program)
             with self._lock:
@@ -246,7 +270,7 @@ class ReconfigEngine:
         return entry.fn, dt
 
     def _get_or_compile(self, key: tuple, kd: KernelDef, bundle: ArgBundle,
-                        devices, origin: str,
+                        device, origin: str,
                         program: str = "chunk") -> CacheEntry:
         """Return the cached entry for ``key``, compiling it if needed.
         Concurrent requests for the same key are deduplicated: one thread
@@ -272,7 +296,7 @@ class ReconfigEngine:
             return inflight.entry
 
         try:
-            fn = self._compile(kd, bundle, devices, program)
+            fn = self._compile(kd, bundle, device, program)
             entry = CacheEntry(fn, origin=origin)
             evicted = self.cache.put(key, entry)
             with self._lock:
@@ -311,10 +335,12 @@ class ReconfigEngine:
             if len(self.key_stats) <= self._KEY_STATS_CAP:
                 break
 
-    def _compile(self, kd: KernelDef, bundle: ArgBundle, devices,
+    def _compile(self, kd: KernelDef, bundle: ArgBundle, device,
                  program: str = "chunk") -> Callable:
-        """AOT-compile the uniform entry point for this signature (the
-        bitstream-generation step).  ``program="chunk"`` compiles
+        """AOT-compile the uniform entry point for this signature on
+        ``device`` (the bitstream-generation step; a hit in JAX's
+        persistent compilation cache stands in for it when one is
+        configured).  ``program="chunk"`` compiles
 
             chunk(ctx, bufs, ints, floats, budget) -> (ctx, bufs, done)
 
@@ -340,17 +366,20 @@ class ReconfigEngine:
             make_pipelined_chunk
         entry = jax.jit(builder(kd.fn), donate_argnums=(0, 1))
         bufs, ints, floats = bundle.padded()
-        ctx = ContextRecord.fresh(budget=kd.default_budget)
-        abstract = lambda t: jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), t)
-        import jax.numpy as jnp
+        on = SingleDeviceSharding(device)
 
-        bufs_a = tuple(abstract(jnp.asarray(b)) for b in bufs)
-        budget_a = jax.ShapeDtypeStruct((), jnp.int32)
+        def spec(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=on)
+
+        def abstract(t):
+            return jax.tree.map(lambda x: spec(x.shape, x.dtype), t)
+
+        ctx = ContextRecord.fresh(budget=kd.default_budget)
+        bufs_a = tuple(spec(np.shape(b), buffer_dtype(b)) for b in bufs)
         args = [abstract(ctx), bufs_a, abstract(ints), abstract(floats),
-                budget_a]
+                spec((), np.int32)]
         if program == "mega":
-            args.append(jax.ShapeDtypeStruct((1,), jnp.int32))
+            args.append(spec((1,), np.int32))
         compiled = entry.lower(*args).compile()
         with self._lock:
             self.stats.total_compile_s += time.perf_counter() - t0
@@ -368,7 +397,7 @@ class ReconfigEngine:
     def prefetch(self, kernel_name: str, bundle: ArgBundle, geometry: tuple,
                  still_wanted: Optional[Callable[[], bool]] = None,
                  origin: str = ORIGIN_PREFETCH,
-                 program: str = "chunk") -> str:
+                 program: str = "chunk", devices=None) -> str:
         """Generate a bitstream off the critical path (no ICAP involvement).
 
         Returns ``"cached"`` (already present or being generated),
@@ -377,7 +406,7 @@ class ReconfigEngine:
         """
         kd = get_kernel(kernel_name)
         key = self.cache_key(kernel_name, bundle.signature(), geometry,
-                             program)
+                             program, devices)
         if key in self.cache:
             return "cached"
         with self._lock:
@@ -387,18 +416,18 @@ class ReconfigEngine:
             with self._lock:
                 self.stats.prefetch_stale_drops += 1
             return "stale"
-        self._get_or_compile(key, kd, bundle, None, origin=origin,
-                             program=program)
+        self._get_or_compile(key, kd, bundle, self.target(devices),
+                             origin=origin, program=program)
         return "compiled"
 
     def prewarm(self, kernel_name: str, bundle: ArgBundle, geometry: tuple,
-                program: str = "chunk"):
+                program: str = "chunk", devices=None):
         """Synchronous up-front warm (compile noise control in benches and
         tests).  Counts as a background compile, but its later demand hits
         are plain cache reuse — NOT prefetch wins — so prewarming a
         no-prefetch baseline cannot inflate the prefetch hit rate."""
         self.prefetch(kernel_name, bundle, geometry, origin=ORIGIN_PREWARM,
-                      program=program)
+                      program=program, devices=devices)
 
     # ------------------------------------------------------------------
     def full_reconfigure(self) -> float:

@@ -49,7 +49,7 @@ from repro.controller.kernels import get_kernel
 from repro.core.context import ContextBank, ContextRecord, Committed
 from repro.core.interrupts import Event, EventKind, InterruptController
 from repro.core.preemption import PreemptFlag
-from repro.core.reconfig import ReconfigEngine
+from repro.core.reconfig import ReconfigEngine, placement_device
 from repro.core.task import Task, TaskStatus
 
 # host-side wait while a device flag snapshot resolves: bounded
@@ -70,6 +70,20 @@ def _device_clone(tree):
     Resume donates the context/payload into the first chunk; cloning keeps
     the bank's committed copy intact for a later REGION_FAILED recovery."""
     return jax.tree.map(lambda a: jnp.array(a, copy=True), tree)
+
+
+def _upload(b, device):
+    """A fresh buffer of ``b`` on ``device`` (``None``: JAX's default).
+
+    Host arrays upload; a device array already there is cloned, and one on
+    another device is copied over device to device — the chunk executable
+    donates its inputs, so the caller's buffer must never be the one
+    passed in."""
+    if isinstance(b, jax.Array):
+        if device is None or b.devices() == {device}:
+            return _device_clone(b)
+        return jax.device_put(b, device)
+    return jax.device_put(np.asarray(b), device)
 
 
 class RegionState(Enum):
@@ -129,6 +143,9 @@ class Region:
         self._track = ("region", rid)
         self._t_preempt_req: Optional[float] = None
         self.devices = devices
+        # the device this region's bitstreams are compiled for and its
+        # buffers live on (None: JAX's default device)
+        self.device = placement_device(devices)
         self.geometry = geometry
         self.chunk_budget = chunk_budget
         # execution engine mode: "sync" | "pipelined" | "megakernel"
@@ -143,7 +160,7 @@ class Region:
         # the megakernel's host-writable preempt flag (one per region —
         # at most one launch is in flight on a region at a time)
         self.flag: Optional[PreemptFlag] = (
-            PreemptFlag() if mode == "megakernel" else None)
+            PreemptFlag(self.device) if mode == "megakernel" else None)
         # device budget scalars by value: a launch re-resolves the budget
         # and re-uploads iff the value changed (the stale-budget fix —
         # the scalar is cached by VALUE, never by task or launch)
@@ -392,31 +409,30 @@ class Region:
           rebalance): materialize the committed host copy on demand and
           upload it here — the only place the spill actually happens.
         """
-        saved: Optional[Committed] = task.saved_context
-        if saved is None:
-            bufs_np, _, _ = task.args.padded()
+        dev = self.device
+
+        def upload(bufs):
             # host buffers upload fresh per dispatch; a buffer that is
             # already a device array (serving rounds thread the previous
-            # round's KV state in directly) must be cloned — the chunk
-            # executable donates its inputs, and the bundle's memoized
-            # buffer must survive for a post-failure re-dispatch
-            return (ContextRecord.fresh(),
-                    tuple(jnp.asarray(b) if isinstance(b, np.ndarray)
-                          else _device_clone(b) for b in bufs_np))
+            # round's KV state in directly) is cloned — the bundle's
+            # memoized buffer must survive for a post-failure re-dispatch
+            return tuple(_upload(b, dev) for b in bufs)
+
+        saved: Optional[Committed] = task.saved_context
+        if saved is None:
+            return (jax.device_put(ContextRecord.fresh(), dev),
+                    upload(task.args.padded()[0]))
         task.saved_context = None
         if saved.device and saved.owner is self:
             self.stats.host_spills_avoided += 1
             ctx = _device_clone(saved.context)
             if saved.payload is not None:
                 return ctx, tuple(_device_clone(b) for b in saved.payload)
-            bufs_np, _, _ = task.args.padded()
-            return ctx, tuple(jnp.asarray(b) for b in bufs_np)
+            return ctx, upload(task.args.padded()[0])
         host = saved.materialize()
-        ctx = jax.tree.map(jnp.asarray, host.context)
-        if host.payload is not None:
-            return ctx, tuple(jnp.asarray(b) for b in host.payload)
-        bufs_np, _, _ = task.args.padded()
-        return ctx, tuple(jnp.asarray(b) for b in bufs_np)
+        ctx = jax.device_put(host.context, dev)
+        return ctx, upload(host.payload if host.payload is not None
+                           else task.args.padded()[0])
 
     # -- launch plumbing shared by every engine mode --------------------
     def _budget_scalar(self, value: int):
@@ -427,7 +443,8 @@ class Region:
         while an unchanged value reuses the cached upload."""
         arr = self._budget_scalars.get(value)
         if arr is None:
-            arr = self._budget_scalars[value] = jnp.int32(value)
+            arr = self._budget_scalars[value] = jax.device_put(
+                np.int32(value), self.device)
         return arr
 
     def _wait_ready(self, snapshot, abort_on_preempt: bool):
@@ -481,6 +498,8 @@ class Region:
 
     def _finish_done(self, task: Task, kd, bufs, t_busy0: float):
         """Completion tail, identical for every engine mode."""
+        task.result_devices = frozenset(d.id for b in bufs
+                                        for d in b.devices())
         task.status = TaskStatus.DONE
         task.t_done = time.perf_counter()
         if kd.device_result:
@@ -512,7 +531,8 @@ class Region:
         self._check_failure()
         kd = get_kernel(task.kernel)
         budget = task.chunk_budget or self.chunk_budget or kd.default_budget
-        _, ints, floats = task.args.padded()  # memoized device scalars
+        # memoized device scalars, on this region's device
+        _, ints, floats = task.args.padded(self.device)
         ctx, bufs = self._prepare(task)
 
         task.status = TaskStatus.RUNNING
@@ -528,14 +548,20 @@ class Region:
         depth = 1 if self.pipeline else 0
         pending: "deque" = deque()  # done snapshots of unretired chunks
         t_last = time.perf_counter()
+        # deterministic preemption point: issue at most ``arm`` chunks this
+        # launch, then commit there unless the task finished (one-shot)
+        arm = task.preempt_at_boundary or None  # 0 = no stop, as the flag
+        task.preempt_at_boundary = None
+        issued = 0
 
         def issue():
-            nonlocal ctx, bufs
+            nonlocal ctx, bufs, issued
             if pending:  # overlapped with an unresolved predecessor
                 self.stats.chunks_pipelined += 1
             ctx, bufs, done = self.executable(ctx, bufs, ints, floats,
                                               budget_arr)
             pending.append(done)
+            issued += 1
 
         tr = self.tracer
 
@@ -575,7 +601,8 @@ class Region:
 
         while True:
             self._check_failure()
-            if self._preempt.is_set():
+            armed_stop = arm is not None and issued >= arm and not pending
+            if self._preempt.is_set() or armed_stop:
                 self._preempt.clear()
                 if drain():  # completion raced the preempt: task is done
                     break
@@ -585,7 +612,7 @@ class Region:
             # keep the pipeline primed: the speculative chunk k+1 is issued
             # before chunk k's done flag is read, so the device never idles
             # across a chunk boundary waiting on the host
-            while len(pending) < depth + 1:
+            while len(pending) < depth + 1 and (arm is None or issued < arm):
                 issue()
 
             # wait for the oldest chunk to resolve.  Pipelined: poll its

@@ -173,6 +173,8 @@ _SERVING = {
     "state_device_rounds": "rounds whose KV state stayed device-resident",
     "engine_mode": "region engine the backend shell runs (None = cluster)",
     "lm": "model backend serving the tokens: surrogate | attention",
+    "reconfig": "nested shell_reconfig report of the serving shell (set by "
+                "the decode driver, launch/serve.py:serve_decode)",
     "kv": "paged-KV block-pool stats (blocks_total/in_use/peak, occupancy, "
           "evictions, reuse, alloc_deferred; DESIGN.md §13) — None for "
           "LMs without a KV cache",

@@ -541,7 +541,7 @@ class Scheduler:
                 continue
             if not prefetcher.alive:  # lazy: the worker starts with the
                 prefetcher.start()    # first hint, never idles otherwise
-            prefetcher.submit(task, self.shell.geometries())
+            prefetcher.submit(task, self.shell.placements())
             self._hinted.add(key)
         if len(self._hinted) > 4096:
             self._hinted &= {(t.tid, t.n_preemptions)

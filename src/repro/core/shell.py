@@ -55,7 +55,8 @@ class Shell:
         self.metrics = metrics
         self.engine = ReconfigEngine(simulate_partial_s=simulate_partial_s,
                                      simulate_full_s=simulate_full_s,
-                                     cache_capacity=cache_capacity)
+                                     cache_capacity=cache_capacity,
+                                     device=self.devices[0])
         self.engine.tracer = tracer
         self.engine.metrics = metrics
         # the worker thread starts lazily with the scheduler's first hint
@@ -154,9 +155,11 @@ class Shell:
     def alive_regions(self) -> List[Region]:
         return [r for r in self.regions if r.alive]
 
-    def geometries(self) -> List[tuple]:
-        """Distinct geometries of alive regions (prefetch targets)."""
-        return list(dict.fromkeys(r.geometry for r in self.alive_regions()))
+    def placements(self) -> List[tuple]:
+        """``(geometry, devices)`` of each alive region — what a bitstream
+        is generated for (prefetch and prewarm targets; the prefetcher
+        drops repeats, and a repeated prewarm is a cache hit)."""
+        return [(r.geometry, r.devices) for r in self.alive_regions()]
 
     def reconfig_report(self) -> dict:
         """Engine + prefetcher + per-region reconfiguration statistics

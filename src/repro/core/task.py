@@ -60,11 +60,11 @@ class Task:
     # value, so a task requeued with a different remaining budget after a
     # preemption provably re-uploads — never reuses a stale scalar.
     chunk_budget: Optional[int] = None
-    # deterministic preemption hook for the megakernel engine (tests, the
-    # serving preempt probe, the overhead bench): the next megakernel
-    # launch of this task writes this value into its preempt flag before
-    # dispatch — the device exits at exactly this chunk boundary — and
-    # clears the field (one-shot).  Ignored by the sync/pipelined engines.
+    # deterministic preemption hook (tests, the serving preempt probe, the
+    # overhead bench, the chip smoke): the next launch of this task stops
+    # at exactly this chunk boundary — the megakernel writes it into its
+    # preempt flag before dispatch, the sync/pipelined engines issue no
+    # chunk past it — and clears the field (one-shot).
     preempt_at_boundary: Optional[int] = None
     # the Sequence this task serves, if any (serving engine back-reference;
     # opaque to the scheduler)
@@ -85,6 +85,9 @@ class Task:
     # serving run's start, so it cannot be recomputed after that run ends)
     deadline_missed: bool = False
     region_history: list = field(default_factory=list)
+    # ids of the devices the final result buffers sat on (set at
+    # completion, before any copy to the host)
+    result_devices: frozenset = frozenset()
     # rid of the region the scheduler last dispatched this task to (loop
     # thread only).  Repair's dropped-command requeue keys on it: a task
     # already re-dispatched to another region must not be requeued again.

@@ -1,6 +1,6 @@
-"""jit'd wrappers for the blur kernels.  The Pallas path is the TPU target
-(validated in interpret mode on CPU); ``use_ref=True`` selects the pure-jnp
-oracle."""
+"""jit'd wrappers for the blur kernels.  The Pallas path is the TPU target:
+compiled where a lowering exists, interpreted on CPU (the mode resolves via
+``kernels.pallas_support``); ``use_ref=True`` selects the pure-jnp oracle."""
 from __future__ import annotations
 
 from functools import partial
@@ -10,6 +10,7 @@ import jax.numpy as jnp
 
 from repro.kernels.blur import kernel as K
 from repro.kernels.blur import ref as R
+from repro.kernels.pallas_support import resolve_interpret
 
 
 @partial(jax.jit, static_argnames=("kind", "use_ref"))
@@ -20,7 +21,8 @@ def blur_block(block: jax.Array, kind: str = "median",
         full = (R.median_blur_ref(block) if kind == "median"
                 else R.gaussian_blur_ref(block))
         return full[1:-1, 1:-1]
-    return K.blur_rows_pallas(block, kind=kind, interpret=True)
+    return K.blur_rows_pallas(block, kind=kind,
+                              interpret=resolve_interpret(None))
 
 
 def blur_rows(src_padded: jax.Array, row_block: int, r, kind: str,

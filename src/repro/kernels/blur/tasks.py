@@ -37,17 +37,25 @@ def _blur_task(ctx: ContextRecord, bufs, ints, floats, kind: str):
     iters = ints[2]
 
     def body_row(ctx, r, state):
-        ping, pong = state
         k = ctx.var[SLOT_K]
-        src = jnp.where(k % 2 == 0, ping, pong)
-        rows = blur_rows(src, ROW_BLOCK, r, kind)
-        dst = jnp.where(k % 2 == 0, pong, ping)
-        dst = jax.lax.dynamic_update_slice(
-            dst, rows.astype(dst.dtype), (r * ROW_BLOCK + 1, 1))
-        ping = jnp.where(k % 2 == 0, ping, dst)
-        pong = jnp.where(k % 2 == 0, dst, pong)
+
+        # one branch per parity, chosen by lax.cond: a whole-image
+        # jnp.where select between the buffers feeding the kernel's
+        # dynamic_update_slice aborts the TPU fusion pass
+        def step(src, dst):
+            rows = blur_rows(src, ROW_BLOCK, r, kind)
+            return jax.lax.dynamic_update_slice(
+                dst, rows.astype(dst.dtype), (r * ROW_BLOCK + 1, 1))
+
+        def even(ping, pong):  # iteration k reads ping, writes pong
+            return ping, step(ping, pong)
+
+        def odd(ping, pong):
+            return step(pong, ping), pong
+
+        state = jax.lax.cond(k % 2 == 0, even, odd, *state)
         ctx = ctx.checkpoint(SLOT_ROW, r + 1)  # paper: checkpoint(row);
-        return ctx, (ping, pong)
+        return ctx, state
 
     def body_k(ctx, k, state):
         # row loop nested under the iteration loop (Listing 1.1 structure)
@@ -70,14 +78,14 @@ def _blur_task(ctx: ContextRecord, bufs, ints, floats, kind: str):
 
 @ctrl_kernel("MedianBlur", backend="PYNQ",
              ktile_args=("input_array", "output_array"),
-             int_args=("H", "W", "iters"), default_budget=8)
+             int_args=("H", "W", "iters"), default_budget=8, pallas=True)
 def median_blur_task(ctx, bufs, ints, floats):
     return _blur_task(ctx, bufs, ints, floats, "median")
 
 
 @ctrl_kernel("GaussianBlur", backend="PYNQ",
              ktile_args=("input_array", "output_array"),
-             int_args=("H", "W", "iters"), default_budget=8)
+             int_args=("H", "W", "iters"), default_budget=8, pallas=True)
 def gaussian_blur_task(ctx, bufs, ints, floats):
     return _blur_task(ctx, bufs, ints, floats, "gaussian")
 

@@ -15,14 +15,19 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# f32 operands contract at f32: on a TPU the default is one bf16 pass
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _dec_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, *, scale, window,
                 bk, S):
     hd = q_ref.shape[-1]
-    pos = pos_ref[0]  # this row's tokens written (current abs pos = pos-1)
+    # this row's tokens written (current abs pos = pos-1); ``pos`` rides
+    # scalar prefetch (SMEM), since a rank-1 block of 1 is refused by Mosaic
+    pos = pos_ref[pl.program_id(0)]
     q = q_ref[0, 0].astype(jnp.float32) * scale  # [1, hd]
     q_pos = pos - 1
 
@@ -38,14 +43,14 @@ def _dec_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, *, scale, window,
         ok = (k_pos >= 0) & (k_pos <= q_pos)
         if window is not None:
             ok &= q_pos - k_pos < window
-        s = q @ k.T  # [1, bk]
+        s = jnp.dot(q, k.T, precision=HIGHEST)  # [1, bk]
         s = jnp.where(ok, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         m_new = jnp.maximum(m_new, -0.5 * jnp.float32(1e30))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
         l_new = l * alpha + p.sum(axis=1, keepdims=True)
-        acc = acc * alpha + p @ v
+        acc = acc * alpha + jnp.dot(p, v, precision=HIGHEST)
         return m_new, l_new, acc
 
     m0 = jnp.full((1, 1), NEG_INF, jnp.float32)
@@ -75,16 +80,20 @@ def decode_attention_pallas(q, k_cache, v_cache, pos, *, window=None,
     if pos_arr.ndim == 0:
         pos_arr = jnp.broadcast_to(pos_arr, (B,))
     assert pos_arr.shape == (B,), pos_arr.shape
-    return pl.pallas_call(
-        kern,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B, H),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h: (b,)),
-            pl.BlockSpec((1, 1, 1, hd), lambda b, h: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, S, hd), lambda b, h: (b, h // g, 0, 0)),
-            pl.BlockSpec((1, 1, S, hd), lambda b, h: (b, h // g, 0, 0)),
+            pl.BlockSpec((1, 1, 1, hd), lambda b, h, pos: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, S, hd), lambda b, h, pos: (b, h // g, 0, 0)),
+            pl.BlockSpec((1, 1, S, hd), lambda b, h, pos: (b, h // g, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, 1, hd), lambda b, h: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, 1, hd),
+                               lambda b, h, pos: (b, h, 0, 0)),
+    )
+    return pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, 1, hd), q.dtype),
         interpret=interpret,
     )(pos_arr, q, k_cache, v_cache)

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 NEG_INF = -1e30
+# f32 operands contract at f32: on a TPU the default is one bf16 pass
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _fa_kernel(qoff_ref, q_ref, k_ref, v_ref, o_ref, *, scale, causal,
@@ -37,7 +39,7 @@ def _fa_kernel(qoff_ref, q_ref, k_ref, v_ref, o_ref, *, scale, causal,
         m, l, acc = carry
         k = k_ref[0, 0, pl.dslice(j * bk, bk), :].astype(jnp.float32)
         v = v_ref[0, 0, pl.dslice(j * bk, bk), :].astype(jnp.float32)
-        s = q @ k.T  # [bq, bk]
+        s = jnp.dot(q, k.T, precision=HIGHEST)  # [bq, bk]
         k_pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
         ok = jnp.ones((bq, bk), bool)
         if causal:
@@ -50,7 +52,7 @@ def _fa_kernel(qoff_ref, q_ref, k_ref, v_ref, o_ref, *, scale, causal,
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
         l_new = l * alpha + p.sum(axis=1, keepdims=True)
-        acc = acc * alpha + p @ v
+        acc = acc * alpha + jnp.dot(p, v, precision=HIGHEST)
         return m_new, l_new, acc
 
     m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)

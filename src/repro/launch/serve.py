@@ -57,9 +57,6 @@ from __future__ import annotations
 
 import argparse
 import time
-import warnings
-
-warnings.filterwarnings("ignore")
 
 import jax
 import jax.numpy as jnp
@@ -292,9 +289,10 @@ def serve_task_stream(*, n_tasks: int = 16, n_regions: int = 2,
             ex = next((t for t in tasks if t.kernel == kname), None)
             if ex is None:
                 continue
-            for geom in shell.geometries():
+            for geom, devs in shell.placements():
                 shell.engine.prewarm(kname, ex.args, geom,
-                                     program=shell.prefetcher.program)
+                                     program=shell.prefetcher.program,
+                                     devices=devs)
 
         shell.region_slowdown_s = 0.02  # deterministic per-chunk work:
         for r in shell.regions:        # fairness and turnaround measure
@@ -420,10 +418,10 @@ def serve_cluster(*, n_shells: int = 2, regions_per_shell: int = 1,
             r.slowdown_s = 0.02
         for kname in kernels:
             ex = next(t for t in tasks if t.kernel == kname)
-            for geom in node.shell.geometries():
+            for geom, devs in node.shell.placements():
                 node.shell.engine.prewarm(
                     kname, ex.args, geom,
-                    program=node.shell.prefetcher.program)
+                    program=node.shell.prefetcher.program, devices=devs)
 
     if fail_after is None:
         fail_after = n_tasks // 2
@@ -487,7 +485,8 @@ def serve_decode(*, n_sequences: int = 6, prompt_len: int = 12,
                  metrics_out: str = None, quiet: bool = False,
                  engine: str = "pipelined", trace_out: str = None,
                  metrics_port: int = None,
-                 metrics_stream: str = None) -> dict:
+                 metrics_stream: str = None,
+                 attn_params=None) -> dict:
     """Token-serving driver (DESIGN.md §9): submit ``n_sequences``
     generation requests through the continuous-batching ``ServingEngine``
     over a preemptive scheduler, verify every streamed sequence against
@@ -500,7 +499,9 @@ def serve_decode(*, n_sequences: int = 6, prompt_len: int = 12,
     mid-flight (the streams must still verify).  ``lm`` selects the model
     backend: ``surrogate`` (integer-hash state, whisper_tiny scale
     d_model=384 / vocab=51865) or ``attention`` (real paged-KV attention
-    decode over Pallas kernels, DESIGN.md §13; d_model=64 / vocab=101).
+    decode over Pallas kernels, DESIGN.md §13; d_model=64 / vocab=101,
+    or the widths of an ``AttentionParams`` given as ``attn_params``,
+    e.g. ``serving.attention.MISTRAL_7B``).
     """
     import json
     import threading
@@ -511,14 +512,16 @@ def serve_decode(*, n_sequences: int = 6, prompt_len: int = 12,
     from repro.serving.kernels import oracle_stream
     from repro.serving.sequence import SamplingParams
 
+    if attn_params is not None:
+        if lm != "attention":
+            raise ValueError("attn_params needs lm='attention'")
+        d_model, vocab = attn_params.d_model, attn_params.vocab
     if d_model is None:
         d_model = 64 if lm == "attention" else 384
     if vocab is None:
         vocab = 101 if lm == "attention" else 51865
     rng = np.random.default_rng(seed)
-    # probing needs real mid-round boundaries: one token per chunk, and
-    # stretched chunks so the probe lands before the round drains (same
-    # slowdown hook the straggler tests use)
+    # probing needs real mid-round boundaries: one token per chunk
     tracer = _make_tracer(trace_out)
     tele = _Telemetry(metrics_port, metrics_stream, quiet=quiet,
                       tag="decode")
@@ -526,12 +529,6 @@ def serve_decode(*, n_sequences: int = 6, prompt_len: int = 12,
                   chunk_budget=1 if preempt_every else 2,
                   simulate_partial_s=partial_s, engine=engine,
                   tracer=tracer, metrics=tele.registry)
-    if preempt_every and engine != "megakernel":
-        # stretch chunks so the probe thread lands mid-round; megakernel
-        # probes arm the deterministic flag write instead (no timing race,
-        # and slowdown_s has no effect inside a single-dispatch launch)
-        for r in shell.regions:
-            r.slowdown_s = 0.02
     sched = Scheduler(shell, SchedulerConfig())
     server = threading.Thread(target=sched.run_forever,
                               name="scheduler-loop", daemon=True)
@@ -543,18 +540,23 @@ def serve_decode(*, n_sequences: int = 6, prompt_len: int = 12,
         prefill_pin, decode_pin = rids[:-1], rids[-1:]
     else:
         prefill_pin = decode_pin = None
+    geometry = {} if attn_params is None else dict(
+        attn_heads=attn_params.n_heads, attn_kv_heads=attn_params.kv_heads,
+        attn_head_dim=attn_params.head_dim,
+        kv_block_size=attn_params.block_size, max_ctx=attn_params.max_ctx,
+        weights_seed=attn_params.seed)
     cfg = ServingConfig(d_model=d_model, vocab_size=vocab, max_slots=slots,
                         round_tokens=round_tokens, lm=lm,
                         prefill_regions=prefill_pin,
                         decode_regions=decode_pin,
-                        preempt_probe_every=preempt_every)
+                        preempt_probe_every=preempt_every, **geometry)
     engine = ServingEngine(sched, cfg).start()
     tele.start(scheduler=sched, serving=engine)
 
     if lm == "attention":
         from repro.serving.attention import (AttentionParams,
                                              attention_oracle_stream)
-        ap = AttentionParams(d_model=d_model, vocab=vocab)
+        ap = attn_params or AttentionParams(d_model=d_model, vocab=vocab)
     specs, handles = [], []
     for i in range(n_sequences):
         plen = int(rng.integers(2, prompt_len + 1))
@@ -587,6 +589,7 @@ def serve_decode(*, n_sequences: int = 6, prompt_len: int = 12,
     tele.close()
     rep = engine.drain(timeout=60.0)
     sched.drain(timeout=60.0)
+    rep["reconfig"] = shell.reconfig_report()
     shell.shutdown()
     _write_trace(tracer, trace_out, quiet, "decode")
     if metrics_out:
@@ -659,6 +662,9 @@ def _translate_legacy(argv):
 def main(argv=None):
     import sys
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     argv = _translate_legacy(sys.argv[1:] if argv is None else list(argv))
 
     # flags shared by every scheduling subcommand

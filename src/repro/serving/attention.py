@@ -86,10 +86,10 @@ class AttentionParams:
         if self.max_ctx % self.block_size:
             raise ValueError(f"max_ctx={self.max_ctx} must be a multiple "
                              f"of block_size={self.block_size}")
-        if self.max_ctx > 128:
-            # flash_attention's default key tile is min(128, S); a larger
-            # context would need S % 128 == 0 plumbing nobody asked for yet
-            raise ValueError(f"max_ctx={self.max_ctx} > 128 unsupported")
+        if self.max_ctx > 128 and self.max_ctx % 128:
+            # both attention kernels stream keys in min(128, S) tiles
+            raise ValueError(f"max_ctx={self.max_ctx} > 128 must be a "
+                             f"multiple of 128")
         for name in ("d_model", "vocab", "n_heads", "kv_heads", "head_dim",
                      "block_size", "max_ctx"):
             if getattr(self, name) < 1:
@@ -102,6 +102,16 @@ class AttentionParams:
     @property
     def table_width(self) -> int:
         return TABLE_META + self.blocks_per_seq
+
+
+# The attention widths of Mistral 7B (mistralai/Mistral-7B-v0.1
+# config.json: hidden 4096, 32 query heads, 8 KV heads, head_dim 128,
+# vocab 32000), cut to one layer and a 2048-position context (the model's
+# is 8192 with a 4096 sliding window), with 16-position KV pages.  The
+# small defaults above are for the CPU tests, where Pallas interprets.
+MISTRAL_7B = AttentionParams(d_model=4096, vocab=32000, n_heads=32,
+                             kv_heads=8, head_dim=128, block_size=16,
+                             max_ctx=2048)
 
 
 # -- weights -------------------------------------------------------------
@@ -135,6 +145,19 @@ def build_weights(p: AttentionParams) -> np.ndarray:
     return w
 
 
+@functools.lru_cache(maxsize=2)
+def device_weights(p: AttentionParams) -> jax.Array:
+    """``build_weights(p)`` uploaded once: every prefill/decode bundle
+    and the oracle share it, so a task's launch copies it on the device
+    instead of uploading it from the host again."""
+    return jnp.asarray(build_weights(p))
+
+
+def _mm(a, b):
+    # f32 contraction at f32 (on a TPU the default is one bf16 pass)
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
 def _split(w, p: AttentionParams):
     """(E, pos_emb, WqT, WkT, WvT, Wo) static views of the flat buffer."""
     e0, pe0, q0, k0, v0, o0, rows = _row_offsets(p)
@@ -166,9 +189,9 @@ def _make_prefill_fn(p: AttentionParams):
             valid = pos[None, :] < plen[:, None]
             x = E[toks] + pe[pos][None, :, :]
             x = jnp.where(valid[..., None], x, 0.0)       # [PB, C, D]
-            q = (x @ wq.T).reshape(PB, C, H, hd).transpose(0, 2, 1, 3)
-            k = (x @ wk.T).reshape(PB, C, KV, hd)
-            v = (x @ wv.T).reshape(PB, C, KV, hd)
+            q = _mm(x, wq.T).reshape(PB, C, H, hd).transpose(0, 2, 1, 3)
+            k = _mm(x, wk.T).reshape(PB, C, KV, hd)
+            v = _mm(x, wv.T).reshape(PB, C, KV, hd)
             k_new = jax.lax.dynamic_update_slice_in_dim(k_new, k, start,
                                                         axis=1)
             v_new = jax.lax.dynamic_update_slice_in_dim(v_new, v, start,
@@ -183,7 +206,7 @@ def _make_prefill_fn(p: AttentionParams):
             # y @ E.T self-dominated (E[tok]·E[tok] ~ D) and greedy
             # decoding would just re-emit the last token forever
             o = o.transpose(0, 2, 1, 3).reshape(PB, C, H * hd)
-            logits = (o @ wo) @ E.T                       # [PB, C, vocab]
+            logits = _mm(_mm(o, wo), E.T)                 # [PB, C, vocab]
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             # a row emits its first token at prompt position plen-1
             emit = jnp.logical_and(valid, pos[None, :] == plen[:, None] - 1)
@@ -225,9 +248,9 @@ def _make_decode_fn(p: AttentionParams):
             posc = jnp.clip(pos, 0, p.max_ctx - 1)
             x = E[table[:, COL_LAST_TOK]] + pe[posc]
             x = jnp.where(live[:, None], x, 0.0)          # [S, D]
-            q = (x @ wq.T).reshape(S, H, 1, hd)
-            k = (x @ wk.T).reshape(S, KV, hd)
-            v = (x @ wv.T).reshape(S, KV, hd)
+            q = _mm(x, wq.T).reshape(S, H, 1, hd)
+            k = _mm(x, wk.T).reshape(S, KV, hd)
+            v = _mm(x, wv.T).reshape(S, KV, hd)
             # scatter this step's K/V into each row's current page; dead
             # rows write zeros to the null page (same-value duplicates,
             # so scatter order can never matter)
@@ -243,7 +266,7 @@ def _make_decode_fn(p: AttentionParams):
             o = paged_decode_attention(q, k_pool, v_pool, tbl,
                                        jnp.where(live, posc + 1, 0))
             # readout without the residual (same rationale as prefill)
-            logits = (o.reshape(S, H * hd) @ wo) @ E.T    # [S, vocab]
+            logits = _mm(_mm(o.reshape(S, H * hd), wo), E.T)  # [S, vocab]
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             out = out.at[:, t].set(jnp.where(live, nxt, out[:, t]))
             table = table.at[:, COL_LAST_TOK].set(
@@ -325,7 +348,7 @@ class AttentionLM:
         self.params = p
         self.cfg = cfg
         self.prefill_name, self.decode_name = register_attention_kernels(p)
-        self.weights = build_weights(p)
+        self.weights = device_weights(p)
         # default pool: enough pages for every slot to hold a full
         # context, so admission can never deadlock (+1 for the null page)
         n_blocks = cfg.kv_blocks or (
@@ -406,7 +429,10 @@ class AttentionLM:
                 assert blocks is not None, "can_admit gated this insert"
                 L = len(seq.prompt)
                 self._pos[sid] = L
-                kn, vn = self._kv_pending.pop(sid)
+                # the prefill region may sit on another device than the
+                # pools: bring its K/V rows over before the scatter
+                kn, vn = jax.device_put(self._kv_pending.pop(sid),
+                                        self.k_pool.sharding)
                 npg = self.pool.blocks_for(L)
                 ids = jnp.asarray(blocks[:npg], jnp.int32)
                 self.k_pool = self.k_pool.at[ids].set(
@@ -488,7 +514,7 @@ def attention_oracle_stream(prompt, max_new_tokens: int,
     batching, chunking, preemption, migration included."""
     p = p or AttentionParams()
     pre_name, dec_name = register_attention_kernels(p)
-    w = build_weights(p)
+    w = device_weights(p)
     BS, T_blk = p.block_size, p.blocks_per_seq
     L = len(prompt)
     if not (0 < L and L + max_new_tokens - 1 <= p.max_ctx):

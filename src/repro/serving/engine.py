@@ -36,6 +36,7 @@ from typing import Dict, List, Optional, Sequence as Seq
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.reporting import safe_rate, stamp
@@ -78,9 +79,9 @@ class ServingConfig:
     prefill_timeout_s: float = 120.0
     round_timeout_s: float = 120.0
     # test/CI hook: force a checkpoint-preempt probe on every Nth decode
-    # round (0 = never).  The probe waits for the round task to start,
-    # then requests a preempt on its region — the round checkpoint-resumes
-    # and must stream bit-identical tokens.
+    # round (0 = never).  The probe arms the round task to stop at its
+    # first chunk boundary — the round checkpoint-resumes and must stream
+    # bit-identical tokens.
     preempt_probe_every: int = 0
     # attention-LM knobs (ignored by the surrogate): model geometry,
     # KV page size, context capacity, and the pool size (None = enough
@@ -177,7 +178,10 @@ class SurrogateLM:
             device_resident = False
         by_slot = dict(occupied)
         for i in inserted:
-            state = state.at[i, :].set(self._state.pop(by_slot[i].sid)[0])
+            # a prefill region may sit on another device than the state
+            row = jax.device_put(self._state.pop(by_slot[i].sid)[0],
+                                 state.sharding)
+            state = state.at[i, :].set(row)
         out = np.zeros((S, R), np.int32)
         kd = get_kernel("SeqDecode")
         return "SeqDecode", kd.bundle(out, state, slots_tbl, S=S, D=D, R=R,
@@ -526,8 +530,8 @@ class ServingEngine:
                         if cfg.decode_regions is not None else None),
         )
         t_round0 = time.perf_counter()
-        th = self.backend.submit(task)
         self._maybe_probe_preempt(task)
+        th = self.backend.submit(task)
         try:
             bufs = th.result(cfg.round_timeout_s)
         except Exception as exc:  # noqa: BLE001 — the round is the blast
@@ -585,36 +589,18 @@ class ServingEngine:
             self.tracer.emit_span("slot_busy", ("slot", slot), t0, tid=sid)
 
     def _maybe_probe_preempt(self, task: Task):
-        """CI/test hook: checkpoint-preempt the round once, mid-flight."""
+        """CI/test hook: checkpoint-preempt the round once, mid-flight
+        (called before the round is submitted, so no launch can miss it)."""
         every = self.cfg.preempt_probe_every
         if not every:
             return
         self._rounds_since_probe += 1
         if self._rounds_since_probe < every:
             return
-        shell = getattr(self.backend, "shell", None)
-        if shell is None:
-            return
         self._rounds_since_probe = 0
-        if getattr(shell, "engine_mode", None) == "megakernel":
-            # megakernel rounds are single dispatches with no host chunk
-            # boundary to race: arm the deterministic one-shot flag write
-            # instead — the device exits at the first chunk boundary
-            task.preempt_at_boundary = 1
-            return
-
-        def probe():
-            deadline = time.perf_counter() + 5.0
-            while time.perf_counter() < deadline:
-                rid = task.last_dispatched_rid
-                if rid is not None and task.n_preemptions == 0:
-                    region = shell.region(rid)
-                    if region.current_task is task:
-                        region.request_preempt()
-                        return
-                time.sleep(0.002)
-
-        threading.Thread(target=probe, daemon=True).start()
+        # deterministic, in every engine: the round's first launch stops
+        # at its first chunk boundary and resumes from the checkpoint
+        task.preempt_at_boundary = 1
 
     # -- settling --------------------------------------------------------
     def _settle(self, seq: Sequence, status: SequenceStatus,

@@ -129,6 +129,38 @@ def test_preempt_at_every_chunk_boundary_bit_identical():
             shell.shutdown()
 
 
+@pytest.mark.parametrize("engine", ["sync", "pipelined"])
+@pytest.mark.parametrize("boundary", [1, 2])
+def test_armed_boundary_stops_host_engines_exactly(engine, boundary):
+    """``Task.preempt_at_boundary`` stops the next launch at exactly that
+    chunk boundary — no sleep, no race — and the resume is bit-identical."""
+    rng = np.random.default_rng(9)
+    img = make_image(rng, SIZE)
+    ref, n_chunks = _reference(img, iters=2)
+    assert n_chunks > boundary
+    shell = Shell(n_regions=1, chunk_budget=2, engine=engine,
+                  prefetch=False)
+    try:
+        t, _ = _blur_task(rng, iters=2, img=img)
+        t.preempt_at_boundary = boundary
+        region = shell.regions[0]
+        region.enqueue_reconfig(t)
+        region.enqueue_launch(t)
+        ev = shell.interrupts.wait(60.0)
+        while ev.kind is EventKind.RECONFIG_DONE:
+            ev = shell.interrupts.wait(60.0)
+        assert ev.kind is EventKind.TASK_PREEMPTED
+        assert region.stats.chunks == boundary   # nothing ran past it
+        assert t.preempt_at_boundary is None     # one-shot
+        region.enqueue_launch(t)
+        ev = shell.interrupts.wait(60.0)
+        assert ev.kind is EventKind.TASK_DONE
+        assert t.n_preemptions == 1
+        assert all(np.array_equal(a, b) for a, b in zip(t.result, ref))
+    finally:
+        shell.shutdown()
+
+
 if HAVE_HYPOTHESIS:
     @settings(max_examples=6, deadline=None,
               suppress_health_check=list(HealthCheck))

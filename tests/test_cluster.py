@@ -5,6 +5,7 @@ zero lost tasks, and leak-free teardown."""
 import threading
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -433,6 +434,43 @@ def test_migrate_to_too_narrow_target_refused(rng):
     finally:
         rep = fe.shutdown()
         assert rep["stranded_handles"] == 0
+
+
+def test_armed_migration_carries_checkpoint_without_racing(rng):
+    """``_migrate_at_boundary(tid, 1)`` right after submit: the task is not
+    cancelled while queued, its first launch stops at chunk boundary 1,
+    and it resumes from that checkpoint on the other shell."""
+    img = make_image(rng, SIZE)
+    ref = _single_shell_reference(
+        lambda iters, img: _blur_task(rng, iters=iters, img=img), 6, img)
+    fe = ClusterFrontend(n_shells=2, regions_per_shell=1, chunk_budget=2,
+                         rebalance=False)
+    try:
+        t = _blur_task(rng, iters=6, img=img)
+        h = fe.submit(t)
+        assert fe._migrate_at_boundary(t.tid, 1, timeout=60.0)
+        np.testing.assert_array_equal(
+            np.asarray(h.result(timeout=120.0)[0]), ref)
+        assert h.n_migrations == 1 and len(set(h.node_history)) == 2
+        assert h.task.n_preemptions == 1
+    finally:
+        rep = fe.shutdown()
+        assert rep["stranded_handles"] == 0
+
+
+@pytest.mark.parametrize("n_devices,own", [(3, True), (2, False)])
+def test_each_shell_owns_a_device_when_there_are_enough(monkeypatch,
+                                                         n_devices, own):
+    """One shell per device when the host has one for every shell (each
+    replica on its own chip); otherwise every shell spans the grid."""
+    devs = [object() for _ in range(n_devices)]
+    monkeypatch.setattr(jax, "devices", lambda *a: devs)
+    fe = ClusterFrontend(n_shells=3, regions_per_shell=1, start=False)
+    try:
+        for i, node in enumerate(fe.nodes):
+            assert node.shell.devices == ([devs[i]] if own else devs)
+    finally:
+        fe.shutdown()
 
 
 # ------------------------------------------------------------ rebalance

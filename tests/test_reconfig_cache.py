@@ -122,7 +122,7 @@ def test_stale_prefetch_for_dequeued_task_is_dropped(rng):
     pf = BitstreamPrefetcher(eng, auto_start=False)  # deterministic stepping
     task = Task(kernel="MedianBlur", args=_bundle(rng))
     task.status = TaskStatus.QUEUED
-    pf.submit(task, [(1,)])
+    pf.submit(task, [((1,), None)])
     task.status = TaskStatus.RUNNING  # dispatched before the prefetcher ran
     pf.drain_once()
     assert eng.stats.prefetch_stale_drops == 1
@@ -133,7 +133,7 @@ def test_stale_prefetch_for_dequeued_task_is_dropped(rng):
     # a still-queued task's hint does compile
     t2 = Task(kernel="GaussianBlur", args=_bundle(rng, "GaussianBlur"))
     t2.status = TaskStatus.QUEUED
-    pf.submit(t2, [(1,)])
+    pf.submit(t2, [((1,), None)])
     pf.drain_once()
     assert eng.stats.prefetch_compiles == 1
     assert len(eng.cache) == 1
@@ -144,9 +144,10 @@ def test_prefetcher_dedupes_geometries_and_bounds_queue(rng):
     pf = BitstreamPrefetcher(eng, max_queue=2, auto_start=False)
     task = Task(kernel="MedianBlur", args=_bundle(rng))
     task.status = TaskStatus.QUEUED
-    pf.submit(task, [(1,), (1,), (2,)])  # duplicate geometry collapses
+    # (geometry, devices) placements; a duplicate collapses
+    pf.submit(task, [((1,), None), ((1,), None), ((2,), None)])
     assert pf.stats.submitted == 2
-    pf.submit(task, [(3,)])              # queue full -> dropped, not stuck
+    pf.submit(task, [((3,), None)])      # queue full -> dropped, not stuck
     assert pf.stats.dropped_full == 1
     pf.drain_once()
     assert pf.wait_idle(timeout=1.0)

@@ -286,3 +286,38 @@ def test_controller_shim_is_deprecated():
             Controller(shell)
     finally:
         shell.shutdown()
+
+
+# ------------------------------------------------------ several devices
+_ACROSS_DEVICES = """
+from repro.core.shell import Shell
+from repro.launch.serve import serve_decode
+
+shell = Shell(n_regions=2)
+devs = [r.device.id for r in shell.regions]
+shell.shutdown()
+assert devs == [0, 2], devs
+rep = serve_decode(lm={lm!r}, n_sequences=8, preempt_every=1, quiet=True)
+assert rep["n_finished"] == 8 and rep["slot_inserts"] > 4, rep
+"""
+
+
+@pytest.mark.parametrize("lm", ["surrogate", "attention"])
+def test_disaggregated_serving_across_devices_of_one_shell(lm):
+    """A two-region shell over four devices runs prefill on device 0 and
+    decode on device 2: every prefill's state must be brought over to the
+    device the decode state lives on.  Eight sequences through four slots,
+    so inserts follow finished rounds; every stream is oracle-checked.
+    A child process, since the device count is fixed when JAX starts."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.path.join(root, "src"))
+    out = subprocess.run([sys.executable, "-c", _ACROSS_DEVICES.format(lm=lm)],
+                         env=env, cwd=root, capture_output=True, text=True,
+                         timeout=300)
+    assert out.returncode == 0, out.stderr[-3000:]
