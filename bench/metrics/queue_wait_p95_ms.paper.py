@@ -1,0 +1,10 @@
+"""Scheduler (``core/scheduler.py``, ``core/policy.py``): 95th percentile
+of the time a task waits in the priority queues, ``Task.t_arrived``
+(admission) to ``Task.t_first_served`` (first launch on a region); a task
+never served counts as infinitely late."""
+from bench.stats import percentile, since_due
+
+
+def read(cell):
+    v = percentile(since_due(cell.records, "t_arrived", "t_first"), 95)
+    return None if v is None else v * 1e3
