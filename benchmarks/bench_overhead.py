@@ -393,7 +393,7 @@ def measure_tracer_overhead(printer=print,
                             size: int = 64, iters: int = 48) -> dict:
     """The flight recorder's dispatch-path cost (DESIGN.md §11): the
     pipelined chunk microbench run untraced vs traced (fresh ``Tracer``
-    per repeat, so every chunk/dispatch/run event is really recorded),
+    per repeat, so every issue/wait/dispatch/run span is really recorded),
     at zero and heavy preemption rates.
 
     The gate — enforced here and in CI — requires the traced arm's

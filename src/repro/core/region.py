@@ -51,6 +51,7 @@ from repro.core.interrupts import Event, EventKind, InterruptController
 from repro.core.preemption import PreemptFlag
 from repro.core.reconfig import ReconfigEngine, placement_device
 from repro.core.task import Task, TaskStatus
+from repro.obs.tracer import NO_SPAN
 
 # host-side wait while a device flag snapshot resolves: bounded
 # exponential backoff instead of a fixed-interval busy-poll — a long
@@ -116,6 +117,9 @@ class RegionStats:
     # megakernel accounting (DESIGN.md §10)
     megakernel_launches: int = 0  # single-dispatch launches
     flag_poll_exits: int = 0      # launches the device exited on the flag
+    # launches that found another program loaded than the task's key and
+    # loaded it first (a dispatch raced the region's queued reconfig)
+    stale_launches: int = 0
     # Pallas dispatch accounting (DESIGN.md §13): which mode the last
     # Pallas-bearing bitstream resolved to ("interpret" | "compiled"),
     # None until one loads — benches read this so they never silently
@@ -365,15 +369,21 @@ class Region:
         """Which compiled entry point this region's mode needs."""
         return "mega" if self.engine_mode == "megakernel" else "chunk"
 
+    def _key(self, task: Task) -> tuple:
+        """The executable a task needs here: its bitstream id."""
+        return (task.kernel, task.args.signature(), self.geometry)
+
     def _do_reconfig(self, task: Task):
         self._check_failure()
-        key = (task.kernel, task.args.signature(), self.geometry)
+        key = self._key(task)
         if self.loaded == key:
             return
         task.status = TaskStatus.RECONFIGURING
-        t_rc0 = time.perf_counter()
-        fn, dt = self.engine.load(task.kernel, task.args, self.geometry,
-                                  self.devices, program=self.program)
+        tr = self.tracer
+        with (tr.span("reconfig", self._track, tid=task.tid,
+                      kernel=task.kernel) if tr is not None else NO_SPAN):
+            fn, dt = self.engine.load(task.kernel, task.args, self.geometry,
+                                      self.devices, program=self.program)
         self.loaded = key
         self.executable = fn
         self.stats.reconfigs += 1
@@ -382,10 +392,6 @@ class Region:
             from repro.kernels.pallas_support import pallas_mode
             self.stats.pallas_mode = pallas_mode()
         task.n_reconfigs += 1
-        tr = self.tracer
-        if tr is not None:
-            tr.emit_span("reconfig", self._track, t_rc0, tid=task.tid,
-                         kernel=task.kernel)
         m = self.metrics
         if m is not None:
             m.histogram("region_reconfig_seconds",
@@ -469,16 +475,18 @@ class Region:
         commit of the device-resident context + partial outputs, then the
         TASK_PREEMPTED interrupt.  The committed host bytes are produced
         on demand by whoever actually needs them."""
-        self.bank.commit(ctx, payload=bufs, tid=task.tid, device=True,
-                         region_rid=self.rid, owner=self)
-        task.saved_context = self.bank.restore()
+        tr = self.tracer
+        with (tr.span("commit", self._track, tid=task.tid)
+              if tr is not None else NO_SPAN):
+            self.bank.commit(ctx, payload=bufs, tid=task.tid, device=True,
+                             region_rid=self.rid, owner=self)
+            task.saved_context = self.bank.restore()
         task.status = TaskStatus.PREEMPTED
         task.n_preemptions += 1
         self.stats.preemptions += 1
         self.current_task = None
         now = time.perf_counter()
         self.stats.busy_s += now - t_busy0
-        tr = self.tracer
         if tr is not None:
             tr.emit_span("run", self._track, t_busy0, tid=task.tid)
             tr.emit("preempt_honored", self._track, tid=task.tid)
@@ -500,21 +508,25 @@ class Region:
         """Completion tail, identical for every engine mode."""
         task.result_devices = frozenset(d.id for b in bufs
                                         for d in b.devices())
-        task.status = TaskStatus.DONE
         task.t_done = time.perf_counter()
+        tr = self.tracer
         if kd.device_result:
             # serving kernels: hand the final device buffers back as-is —
             # the engine streams the token buffer host-side but threads the
             # KV state into the next round without a host round trip
             task.result = tuple(bufs)
         else:
-            task.result = tuple(np.asarray(jax.device_get(b))
-                                for b in bufs[:2])
+            with (tr.span("readback", self._track, tid=task.tid)
+                  if tr is not None else NO_SPAN):
+                task.result = tuple(np.asarray(jax.device_get(b))
+                                    for b in bufs[:2])
+        # DONE only once the result is there: a caller polling the status
+        # (or the scheduler settling handles at exit) reads it at once
+        task.status = TaskStatus.DONE
         self.stats.kernels_run += 1
         self.current_task = None
         now = time.perf_counter()
         self.stats.busy_s += now - t_busy0
-        tr = self.tracer
         if tr is not None:
             tr.emit_span("run", self._track, t_busy0, tid=task.tid)
             tr.emit("done", self._track, tid=task.tid)
@@ -529,11 +541,23 @@ class Region:
     # -- the chunk-pipelined execution hot path -------------------------
     def _do_launch(self, task: Task):
         self._check_failure()
+        tr = self.tracer
+        if self.loaded != self._key(task):
+            # the launch was posted against another load than the one it
+            # needs (a dispatch raced the region's queued commands): load
+            # the task's program first instead of calling the wrong one
+            self.stats.stale_launches += 1
+            if tr is not None:
+                tr.emit("stale_launch", self._track, tid=task.tid,
+                        kernel=task.kernel)
+            self._do_reconfig(task)
         kd = get_kernel(task.kernel)
         budget = task.chunk_budget or self.chunk_budget or kd.default_budget
-        # memoized device scalars, on this region's device
-        _, ints, floats = task.args.padded(self.device)
-        ctx, bufs = self._prepare(task)
+        with (tr.span("prepare", self._track, tid=task.tid)
+              if tr is not None else NO_SPAN):
+            # memoized device scalars, on this region's device
+            _, ints, floats = task.args.padded(self.device)
+            ctx, bufs = self._prepare(task)
 
         task.status = TaskStatus.RUNNING
         task.region_history.append(self.rid)
@@ -558,25 +582,21 @@ class Region:
             nonlocal ctx, bufs, issued
             if pending:  # overlapped with an unresolved predecessor
                 self.stats.chunks_pipelined += 1
-            ctx, bufs, done = self.executable(ctx, bufs, ints, floats,
-                                              budget_arr)
+            with (tr.span("issue", self._track, tid=task.tid)
+                  if tr is not None else NO_SPAN):
+                ctx, bufs, done = self.executable(ctx, bufs, ints, floats,
+                                                  budget_arr)
             pending.append(done)
             issued += 1
-
-        tr = self.tracer
 
         def retire(done: int):
             """Account one resolved chunk boundary (EWMA, per-task work)."""
             nonlocal t_last
-            t_prev = t_last
             dt = time.perf_counter() - t_last
             if self.slowdown_s:
                 time.sleep(self.slowdown_s)
                 dt += self.slowdown_s
             t_last = time.perf_counter()
-            if tr is not None:
-                tr.emit("chunk", self._track, tid=task.tid,
-                        t=t_prev, dur=dt)
             a = 0.3
             self.stats.chunk_ewma_s = (
                 dt if self.stats.chunks == 0
@@ -591,7 +611,9 @@ class Region:
             discarded.  Returns whether the task actually finished."""
             done = 0
             while pending:
-                v = int(pending.popleft())
+                with (tr.span("wait", self._track, tid=task.tid)
+                      if tr is not None else NO_SPAN):
+                    v = int(pending.popleft())
                 if done:
                     self.stats.chunks_discarded += 1
                 else:
@@ -621,12 +643,14 @@ class Region:
             # speculative chunk, so this wait never blocks dispatch.
             # Synchronous (depth 0): block on the flag directly, exactly
             # the seed's per-chunk host round trip.
-            if depth:
-                self._wait_ready(pending[0], abort_on_preempt=True)
-                if self._preempt.is_set() or self._failed.is_set():
-                    continue  # handled at the loop top
-
-            if retire(int(pending.popleft())):
+            with (tr.span("wait", self._track, tid=task.tid)
+                  if tr is not None else NO_SPAN):
+                if depth:
+                    self._wait_ready(pending[0], abort_on_preempt=True)
+                    if self._preempt.is_set() or self._failed.is_set():
+                        continue  # handled at the loop top
+                done = int(pending.popleft())
+            if retire(done):
                 # remaining in-flight chunks were done-gated to identity:
                 # current ctx/bufs are bit-identical to the final state
                 self.stats.chunks_discarded += len(pending)

@@ -33,7 +33,6 @@ import heapq
 import itertools
 import threading
 import time
-from collections import deque
 from dataclasses import dataclass
 from typing import List, Optional
 
@@ -44,6 +43,7 @@ from repro.core.reporting import safe_rate, stamp
 from repro.obs.metrics import trace_section
 from repro.obs.registry import RATIO_BUCKETS
 from repro.obs.slo import size_class, telemetry_section
+from repro.obs.tracer import NO_SPAN
 from repro.core.region import Region, RegionState
 from repro.core.shell import Shell
 from repro.core.submit import SubmissionQueue, TaskHandle
@@ -172,8 +172,6 @@ class Scheduler:
         self.deadline_misses_total = 0
         self._dead_since = {}
         self._last_ckpt = 0.0
-        # debugging trace, bounded so server mode cannot grow it forever
-        self.events_log: deque = deque(maxlen=65536)
         self.last_report: Optional[dict] = None
 
         # admission layer
@@ -553,8 +551,18 @@ class Scheduler:
             self._preempt_pending)
 
     def _handle(self, ev: Event, quiet=True):
-        self.events_log.append((self.now(), ev.kind.value, ev.region_id,
-                                getattr(ev.task, "tid", None)))
+        tr = self.tracer
+        if tr is None:
+            self._on_event(ev, quiet)
+            return
+        with tr.span("handle", self._trace_track,
+                     tid=getattr(ev.task, "tid", None),
+                     event=ev.kind.value, rid=ev.region_id) as sp:
+            # how long the interrupt waited for the loop to take it
+            sp.attrs["lag_s"] = sp.t - ev.t
+            self._on_event(ev, quiet)
+
+    def _on_event(self, ev: Event, quiet: bool):
         if ev.kind == EventKind.TASK_DONE:
             self.finished.append(ev.task)
             if ev.region_id in self._preempt_pending:
@@ -715,22 +723,21 @@ class Scheduler:
 
     def _dispatch(self, region: Region, task: Task, quiet=True):
         tr = self.tracer
-        if tr is not None:
-            tr.emit("dispatch", self._trace_track, tid=task.tid,
-                    rid=region.rid)
-        m = self.metrics
-        if m is not None:
-            m.counter("dispatches_total", tenant=task.tenant,
-                      phase=task.phase or "task").inc()
-        task.last_dispatched_rid = region.rid
-        key = (task.kernel, task.args.signature(), region.geometry)
-        if self.cfg.full_reconfig_mode:
+        with (tr.span("dispatch", self._trace_track, tid=task.tid,
+                      rid=region.rid) if tr is not None else NO_SPAN):
+            m = self.metrics
+            if m is not None:
+                m.counter("dispatches_total", tenant=task.tenant,
+                          phase=task.phase or "task").inc()
+            task.last_dispatched_rid = region.rid
+            key = (task.kernel, task.args.signature(), region.geometry)
+            if self.cfg.full_reconfig_mode:
+                if region.loaded != key:
+                    self._full_reconfigure(key, quiet)
+                    region.loaded = None  # force the (re)load below
             if region.loaded != key:
-                self._full_reconfigure(key, quiet)
-                region.loaded = None  # force the (re)load below
-        if region.loaded != key:
-            region.enqueue_reconfig(task)
-        region.enqueue_launch(task)
+                region.enqueue_reconfig(task)
+            region.enqueue_launch(task)
         if not quiet:
             print(f"[{self.now():7.3f}] launch {task} -> R{region.rid}")
 

@@ -18,13 +18,28 @@ Design constraints (DESIGN.md §11):
   same clock every latency number in the repo already uses — so trace
   events and ``report()`` walls are directly comparable.  ``t0`` is
   recorded at construction for export-time normalization.
+- **One span, two records.**  ``span()`` also opens a
+  ``jax.profiler.TraceAnnotation`` named ``<track><id>.<kind>`` (e.g.
+  ``region1.wait``) around its body, so while a profiler session is live
+  the span lands on the executing thread's line of the profiler's host
+  plane, on the device trace's clock, with the task id as its ``tid``
+  stat.  Outside a session the annotation is a no-op of about a
+  microsecond.  Sites guard it like an emit: with tracing off they enter
+  the shared ``NO_SPAN`` and build nothing.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from collections import deque
 from typing import NamedTuple, Optional
+
+from jax.profiler import TraceAnnotation
+
+# what a site enters when its tracer is None: one shared no-op context,
+# so the untraced path allocates nothing
+NO_SPAN = contextlib.nullcontext()
 
 
 class TraceEvent(NamedTuple):
@@ -86,6 +101,14 @@ class Tracer:
         self.emit(kind, track, tid=tid, t=t_start,
                   dur=max(end - t_start, 0.0), **attrs)
 
+    def span(self, kind: str, track: tuple, /, tid: Optional[int] = None,
+             **attrs) -> "Span":
+        """Context manager recording its body as one span: the ring event
+        ``emit_span`` would record, and the profiler annotation
+        ``<track><id>.<kind>`` (``tid`` as an event stat) around it.
+        Attrs may be added to ``Span.attrs`` inside the body."""
+        return Span(self, kind, track, tid, attrs)
+
     # -- inspection --------------------------------------------------------
 
     def events(self) -> "list[TraceEvent]":
@@ -111,3 +134,31 @@ class Tracer:
     def __repr__(self) -> str:  # pragma: no cover - debug aid
         return (f"Tracer(capacity={self.capacity}, recorded={len(self)}, "
                 f"dropped={self.dropped})")
+
+
+class Span:
+    """One :meth:`Tracer.span`: the annotation is entered before the start
+    time is taken and left after the end time, so the profiler's event
+    holds the ring's."""
+
+    __slots__ = ("_tracer", "kind", "track", "tid", "attrs", "_ann", "t")
+
+    def __init__(self, tracer: Tracer, kind: str, track: tuple,
+                 tid: Optional[int], attrs: dict):
+        self._tracer = tracer
+        self.kind, self.track, self.tid, self.attrs = kind, track, tid, attrs
+
+    def __enter__(self) -> "Span":
+        name = f"{self.track[0]}{self.track[1]}.{self.kind}"
+        ann = (TraceAnnotation(name) if self.tid is None
+               else TraceAnnotation(name, tid=self.tid))
+        ann.__enter__()
+        self._ann = ann
+        self.t = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        end = time.perf_counter()
+        self._ann.__exit__(*exc)
+        self._tracer.emit(self.kind, self.track, tid=self.tid, t=self.t,
+                          dur=end - self.t, **self.attrs)
