@@ -41,9 +41,9 @@ class ArgBundle:
     _padded: Optional[tuple] = field(default=None, repr=False, compare=False)
     _scalars: dict = field(default_factory=dict, repr=False, compare=False)
 
-    def padded(self, device=None):
-        """``(bufs, ints, floats)`` at the uniform ABI width, with the
-        scalar vectors on ``device`` (``None``: JAX's default device)."""
+    def host(self):
+        """``(bufs, ints, floats)`` at the uniform ABI width, as host
+        numpy (memoized): nothing is uploaded."""
         if self._padded is None:
             bufs = list(self.bufs)[:N_BUF_SLOTS]
             while len(bufs) < N_BUF_SLOTS:
@@ -54,18 +54,37 @@ class ArgBundle:
             floats += [0.0] * (N_FLOAT_ARGS - len(floats))
             self._padded = (tuple(bufs), np.asarray(ints, np.int32),
                             np.asarray(floats, np.float32))
-        bufs, ints, floats = self._padded
-        scalars = self._scalars.get(device)
-        if scalars is None:
-            scalars = self._scalars[device] = (
-                jax.device_put(ints, device), jax.device_put(floats, device))
+        return self._padded
+
+    @property
+    def n_dummies(self) -> int:
+        """How many of ``host()``'s buffer slots are ``(1, 1)`` dummies
+        (the last ones)."""
+        return N_BUF_SLOTS - min(len(self.bufs), N_BUF_SLOTS)
+
+    def scalars(self, device=None):
+        """The memoized device ``(ints, floats)`` on ``device``, or None
+        until an upload there is kept (``keep_scalars``)."""
+        return self._scalars.get(device)
+
+    def keep_scalars(self, device, scalars):
+        """Memoize ``(ints, floats)`` uploaded to ``device``; returns them."""
+        scalars = self._scalars[device] = tuple(scalars)
+        return scalars
+
+    def padded(self, device=None):
+        """``(bufs, ints, floats)`` at the uniform ABI width, with the
+        scalar vectors on ``device`` (``None``: JAX's default device)."""
+        bufs, ints, floats = self.host()
+        scalars = self.scalars(device) or self.keep_scalars(
+            device, jax.device_put((ints, floats), device))
         return (bufs,) + scalars
 
     def signature(self) -> tuple:
         """Shape/dtype signature — the 'interface' a region must be
         configured for (kernel + signature = one executable)."""
         if self._sig is None:
-            bufs, _, _ = self.padded()
+            bufs, _, _ = self.host()
             self._sig = tuple((tuple(b.shape), buffer_dtype(b).name)
                               for b in bufs)
         return self._sig

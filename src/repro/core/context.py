@@ -151,11 +151,9 @@ class Committed:
             return self
         with self._mat_lock:
             if self._host is None:
-                host_ctx = jax.tree.map(
-                    lambda x: jax.device_get(x), self.context)
-                host_payload = (jax.tree.map(
-                    lambda x: jax.device_get(x), self.payload)
-                    if self.payload is not None else None)
+                # one batched device-to-host copy of every leaf
+                host_ctx, host_payload = jax.device_get(
+                    (self.context, self.payload))
                 self._host = Committed(self.seqno, host_ctx, host_payload,
                                        tid=self.tid)
             return self._host

@@ -33,6 +33,7 @@ bytes — a flag-exited megakernel feeds the exact same commit machinery.
 """
 from __future__ import annotations
 
+import functools
 import queue
 import threading
 import time
@@ -65,26 +66,14 @@ _POLL_MAX_S = 1e-3
 ENGINE_MODES = ("sync", "pipelined", "megakernel")
 
 
-def _device_clone(tree):
-    """Device-side copy of a pytree of arrays (no host round trip).
-
-    Resume donates the context/payload into the first chunk; cloning keeps
-    the bank's committed copy intact for a later REGION_FAILED recovery."""
-    return jax.tree.map(lambda a: jnp.array(a, copy=True), tree)
-
-
-def _upload(b, device):
-    """A fresh buffer of ``b`` on ``device`` (``None``: JAX's default).
-
-    Host arrays upload; a device array already there is cloned, and one on
-    another device is copied over device to device — the chunk executable
-    donates its inputs, so the caller's buffer must never be the one
-    passed in."""
-    if isinstance(b, jax.Array):
-        if device is None or b.devices() == {device}:
-            return _device_clone(b)
-        return jax.device_put(b, device)
-    return jax.device_put(np.asarray(b), device)
+@functools.partial(jax.jit, static_argnums=0)
+def _fresh_state(n_dummies: int):
+    """A fresh launch's context (``ContextRecord.fresh()``) and its
+    ``n_dummies`` unused buffer slots (``ArgBundle``'s ``(1, 1)`` float32
+    dummies), made on the device by one small program, compiled once per
+    count and device: one dispatch instead of an upload per leaf."""
+    return ContextRecord.fresh(), tuple(
+        jnp.zeros((1, 1), jnp.float32) for _ in range(n_dummies))
 
 
 class RegionState(Enum):
@@ -402,43 +391,56 @@ class Region:
 
     # -- launch argument preparation ------------------------------------
     def _prepare(self, task: Task):
-        """Initial (ctx, bufs) for a launch, reusing device-resident state
-        wherever possible.
+        """A launch's ``(ctx, bufs, ints, floats)`` on this region's
+        device, the path taken and the host-to-device calls made:
 
-        - fresh launch: pad-and-upload the argument buffers (``padded()``
-          is memoized per bundle, so a requeued task never re-pads);
-        - resume on the *same* region: the committed context/payload never
-          left device memory — clone it device-side (the bank keeps the
-          committed copy for failure recovery) and skip the host round
-          trip entirely;
-        - resume on a *different* region (migration, failover, elastic
-          rebalance): materialize the committed host copy on demand and
-          upload it here — the only place the spill actually happens.
-        """
+        - ``fresh`` (two calls): the context and the dummy buffer slots
+          come from one small compiled program (``_fresh_state``), the
+          bundle's real buffers (``host()``, memoized per bundle, so a
+          requeued task never re-pads) from one ``jax.device_put``;
+        - ``resume_local`` (resume on the *same* region, one call): the
+          committed context/payload never left device memory and is
+          copied there;
+        - ``resume_host`` (migration, failover, elastic rebalance; one
+          call): the committed host copy is materialized on demand and
+          uploaded — the only place the spill actually happens.
+
+        The chunk executable donates ``ctx`` and ``bufs``, so every leaf
+        comes out a buffer of its own (``may_alias=False``): host leaves
+        upload; a device array is copied, never aliased — on this device
+        device-side (the bank keeps its committed copy for a
+        REGION_FAILED recovery, a bundle its buffers for a re-dispatch;
+        serving threads its KV state in as device arrays), from another
+        device device to device.  The int/float vectors join the
+        ``device_put`` of a bundle's first launch on a device and are
+        memoized on the bundle."""
         dev = self.device
-
-        def upload(bufs):
-            # host buffers upload fresh per dispatch; a buffer that is
-            # already a device array (serving rounds thread the previous
-            # round's KV state in directly) is cloned — the bundle's
-            # memoized buffer must survive for a post-failure re-dispatch
-            return tuple(_upload(b, dev) for b in bufs)
-
+        args = task.args
+        host_bufs, ints, floats = args.host()
+        scalars = args.scalars(dev)
+        put = (ints, floats) if scalars is None else None
         saved: Optional[Committed] = task.saved_context
         if saved is None:
-            return (jax.device_put(ContextRecord.fresh(), dev),
-                    upload(task.args.padded()[0]))
-        task.saved_context = None
-        if saved.device and saved.owner is self:
-            self.stats.host_spills_avoided += 1
-            ctx = _device_clone(saved.context)
-            if saved.payload is not None:
-                return ctx, tuple(_device_clone(b) for b in saved.payload)
-            return ctx, upload(task.args.padded()[0])
-        host = saved.materialize()
-        ctx = jax.device_put(host.context, dev)
-        return ctx, upload(host.payload if host.payload is not None
-                           else task.args.padded()[0])
+            path, calls, n = "fresh", 2, args.n_dummies
+            with jax.default_device(dev):
+                ctx, dummies = _fresh_state(n)
+            real, put = jax.device_put(
+                (host_bufs[:len(host_bufs) - n], put), dev, may_alias=False)
+            bufs = real + dummies
+        else:
+            task.saved_context = None
+            if saved.device and saved.owner is self:
+                self.stats.host_spills_avoided += 1
+                path = "resume_local"
+            else:
+                path, saved = "resume_host", saved.materialize()
+            payload = host_bufs if saved.payload is None else saved.payload
+            ctx, bufs, put = jax.device_put(
+                (saved.context, tuple(payload), put), dev, may_alias=False)
+            calls = 1
+        if scalars is None:
+            scalars = args.keep_scalars(dev, put)
+        return (ctx, bufs) + scalars, path, calls
 
     # -- launch plumbing shared by every engine mode --------------------
     def _budget_scalar(self, value: int):
@@ -554,10 +556,10 @@ class Region:
         kd = get_kernel(task.kernel)
         budget = task.chunk_budget or self.chunk_budget or kd.default_budget
         with (tr.span("prepare", self._track, tid=task.tid)
-              if tr is not None else NO_SPAN):
-            # memoized device scalars, on this region's device
-            _, ints, floats = task.args.padded(self.device)
-            ctx, bufs = self._prepare(task)
+              if tr is not None else NO_SPAN) as sp:
+            (ctx, bufs, ints, floats), path, calls = self._prepare(task)
+            if sp is not None:
+                sp.attrs.update(path=path, calls=calls)
 
         task.status = TaskStatus.RUNNING
         task.region_history.append(self.rid)
