@@ -102,6 +102,7 @@ class RegionStats:
     # chunk-pipeline accounting (DESIGN.md §8)
     chunks_pipelined: int = 0   # chunks issued while a predecessor resolved
     chunks_discarded: int = 0   # speculative identity chunks past done
+    done_prefetches: int = 0    # done snapshots copied to the host at issue
     host_spills_avoided: int = 0  # device-resident resumes (no host copy)
     # megakernel accounting (DESIGN.md §10)
     megakernel_launches: int = 0  # single-dispatch launches
@@ -455,22 +456,28 @@ class Region:
                 np.int32(value), self.device)
         return arr
 
-    def _wait_ready(self, snapshot, abort_on_preempt: bool):
+    def _wait_ready(self, snapshot, abort_on_preempt: bool) -> bool:
         """Wait for a device flag snapshot with bounded exponential
         backoff (``_POLL_MIN_S`` doubling to ``_POLL_MAX_S``): long chunks
         no longer spin a host core at a fixed interval, short ones still
         resolve promptly.  Returns early when the region fails — or, if
         ``abort_on_preempt``, when a preempt request needs the host loop's
         attention (the pipelined engine handles it between chunks; the
-        megakernel's preemption is device-side, so it keeps waiting)."""
+        megakernel's preemption is device-side, so it keeps waiting).
+        Returns whether the first poll already found the snapshot
+        resolved."""
+        if snapshot.is_ready():
+            return True
         delay = _POLL_MIN_S
-        while not snapshot.is_ready():
+        while True:
             if self._failed.is_set():
-                return
+                return False
             if abort_on_preempt and self._preempt.is_set():
-                return
+                return False
             time.sleep(delay)
             delay = min(delay * 2.0, _POLL_MAX_S)
+            if snapshot.is_ready():
+                return False
 
     def _commit_preempt(self, task: Task, ctx, bufs, t_busy0: float):
         """Preemption tail, identical for every engine mode: lazy-spill
@@ -588,6 +595,14 @@ class Region:
                   if tr is not None else NO_SPAN):
                 ctx, bufs, done = self.executable(ctx, bufs, ints, floats,
                                                   budget_arr)
+            if depth:
+                # queue the flag's copy to the host behind the chunk: the
+                # read at this chunk's boundary, one issue later, then
+                # finds the value there instead of starting the device-to-
+                # host round trip itself.  Sync reads at once: nothing to
+                # overlap, so it keeps the plain blocking read.
+                done.copy_to_host_async()
+                self.stats.done_prefetches += 1
             pending.append(done)
             issued += 1
 
@@ -613,7 +628,9 @@ class Region:
             discarded.  Returns whether the task actually finished."""
             done = 0
             while pending:
-                with (tr.span("wait", self._track, tid=task.tid)
+                with (tr.span("wait", self._track, tid=task.tid,
+                              prefetched=bool(depth),
+                              ready=pending[0].is_ready())
                       if tr is not None else NO_SPAN):
                     v = int(pending.popleft())
                 if done:
@@ -645,12 +662,18 @@ class Region:
             # speculative chunk, so this wait never blocks dispatch.
             # Synchronous (depth 0): block on the flag directly, exactly
             # the seed's per-chunk host round trip.
-            with (tr.span("wait", self._track, tid=task.tid)
-                  if tr is not None else NO_SPAN):
+            with (tr.span("wait", self._track, tid=task.tid,
+                          prefetched=bool(depth))
+                  if tr is not None else NO_SPAN) as sp:
                 if depth:
-                    self._wait_ready(pending[0], abort_on_preempt=True)
+                    ready = self._wait_ready(pending[0],
+                                             abort_on_preempt=True)
+                    if sp is not None:
+                        sp.attrs["ready"] = ready
                     if self._preempt.is_set() or self._failed.is_set():
                         continue  # handled at the loop top
+                elif sp is not None:
+                    sp.attrs["ready"] = pending[0].is_ready()
                 done = int(pending.popleft())
             if retire(done):
                 # remaining in-flight chunks were done-gated to identity:

@@ -26,17 +26,18 @@ from repro.core.scheduler import Scheduler, SchedulerConfig
 from repro.core.shell import Shell
 from repro.core.task import Task, TaskStatus
 from repro.kernels.blur.tasks import make_image
+from repro.obs import Tracer
 
 SIZE = 30
 
 
 def _blur_task(rng, iters=2, kernel="MedianBlur", img=None, priority=2,
-               deadline_s=None, tenant="default"):
+               deadline_s=None, tenant="default", size=SIZE):
     if img is None:
-        img = make_image(rng, SIZE)
+        img = make_image(rng, size)
     kd = get_kernel(kernel)
     t = Task(kernel=kernel,
-             args=kd.bundle(img, np.zeros_like(img), H=SIZE, W=SIZE,
+             args=kd.bundle(img, np.zeros_like(img), H=size, W=size,
                             iters=iters),
              priority=priority, deadline_s=deadline_s, tenant=tenant)
     return t, img
@@ -206,6 +207,105 @@ def _check_pipelined_equivalence(budget, iters, kernel, preempt_at, seed):
         assert all(np.array_equal(a, b) for a, b in zip(t.result, ref))
     finally:
         pipe.shutdown()
+
+
+# ------------------------------------------------- done snapshot prefetch
+def _run_engine(engine, kernel, iters, img, size, budget, tracer=None):
+    """One uninterrupted task on a fresh one-region shell of ``engine``:
+    its host results and the region's stats."""
+    shell = Shell(n_regions=1, chunk_budget=budget, engine=engine,
+                  prefetch=False, tracer=tracer)
+    try:
+        t, _ = _blur_task(np.random.default_rng(0), iters=iters,
+                          kernel=kernel, img=img, size=size)
+        _drive(shell, t)
+        return tuple(np.asarray(b) for b in t.result), shell.regions[0].stats
+    finally:
+        shell.shutdown()
+
+
+def _wait_spans(tracer):
+    return [e for e in tracer.events() if e.kind == "wait"]
+
+
+@pytest.mark.parametrize("n_chunks", [1, 2, 4])
+@pytest.mark.parametrize("kernel,iters", [
+    ("MedianBlur", 1), ("MedianBlur", 2), ("MedianBlur", 3),
+    ("GaussianBlur", 1)])
+def test_prefetched_done_reads_bit_identical_to_sync(kernel, iters,
+                                                     n_chunks):
+    """Pipelined chunks start their ``done`` snapshot's host copy at issue;
+    the results stay bit-identical to ``sync``, which starts none.  A pass
+    costs one budget unit a 32-row block plus one of its own, so a padded
+    side of ``128 * n_chunks`` at a budget of ``5 * iters`` needs
+    ``n_chunks`` chunks (asserted on both engines)."""
+    side = 128 * n_chunks
+    img = make_image(np.random.default_rng(n_chunks), side - 10)
+    ref, sync = _run_engine("sync", kernel, iters, img, side, 5 * iters)
+    out, pipe = _run_engine("pipelined", kernel, iters, img, side,
+                            5 * iters)
+    assert sync.chunks == pipe.chunks == n_chunks
+    assert all(np.array_equal(a, b) for a, b in zip(out, ref))
+    assert sync.done_prefetches == 0
+    # every chunk issued, the speculative one past completion too
+    assert pipe.chunks_discarded == 1
+    assert pipe.done_prefetches == pipe.chunks + pipe.chunks_discarded
+
+
+@pytest.mark.parametrize("engine", ["sync", "pipelined", "megakernel"])
+def test_done_prefetches_count_pipelined_issues_only(engine):
+    """``RegionStats.done_prefetches`` equals the chunk executable calls
+    (the ``issue`` spans) in ``pipelined`` and stays 0 elsewhere; every
+    ``wait`` span says whether its snapshot was prefetched and whether
+    its first poll found it resolved."""
+    img = make_image(np.random.default_rng(22), SIZE)
+    tr = Tracer()
+    _, stats = _run_engine(engine, "MedianBlur", 2, img, SIZE, 2,
+                           tracer=tr)
+    issues = [e for e in tr.events() if e.kind == "issue"]
+    waits = _wait_spans(tr)
+    if engine == "pipelined":
+        assert stats.done_prefetches == len(issues) > 1
+    else:
+        assert stats.done_prefetches == 0
+    assert len(waits) == (0 if engine == "megakernel" else stats.chunks)
+    for e in waits:
+        assert e.attrs["prefetched"] is (engine == "pipelined")
+        assert isinstance(e.attrs["ready"], bool)
+
+
+@pytest.mark.parametrize("how", ["armed", "requested"])
+def test_preempted_pipeline_reads_prefetched_snapshots(how):
+    """A pipelined task preempted at a chunk boundary, set
+    (``preempt_at_boundary``) or requested while chunks are in flight,
+    commits there and resumes to the synchronous answer; every snapshot
+    it read, ``drain()``'s on the preemption path among them, had its
+    host copy started at issue."""
+    rng = np.random.default_rng(23)
+    img = make_image(rng, SIZE)
+    ref, n_chunks = _reference(img, iters=3)
+    tr = Tracer()
+    shell = Shell(n_regions=1, chunk_budget=2, prefetch=False, tracer=tr)
+    region = shell.regions[0]
+    try:
+        t, _ = _blur_task(rng, iters=3, img=img)
+        if how == "armed":
+            t.preempt_at_boundary = 2
+            assert _drive(shell, t) == 1
+            assert region.stats.chunks == n_chunks  # none ran twice
+        else:
+            region.slowdown_s = 0.02  # the request lands mid-pipeline
+            assert _drive(shell, t, preempt_at=1) >= 1
+        stats = region.stats
+    finally:
+        shell.shutdown()
+    assert t.status is TaskStatus.DONE and t.n_preemptions >= 1
+    assert all(np.array_equal(a, b) for a, b in zip(t.result, ref))
+    assert stats.done_prefetches == stats.chunks + stats.chunks_discarded
+    waits = _wait_spans(tr)
+    assert len(waits) >= stats.chunks
+    assert all(e.attrs["prefetched"] is True
+               and isinstance(e.attrs["ready"], bool) for e in waits)
 
 
 # ------------------------------------------------------------- lazy spill
